@@ -15,8 +15,10 @@ style hardware (DESIGN.md §8.1):
 """
 from __future__ import annotations
 
+import os
+import subprocess
 import time
-from typing import Callable, Dict
+from typing import Callable, Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,24 @@ import numpy as np
 
 from repro.core.blotter import build_opbatch
 from repro.core.engines import evaluate
+
+
+def cpu_worker(cmd: List[str], *, timeout: float):
+    """Run a forced-host-device worker on the CPU backend.
+
+    The sharded workers build an 8-device mesh out of host CPU devices.
+    With ``JAX_PLATFORMS=cpu`` in its environment such a child never
+    reaches for an accelerator, which on a chip host is already held by
+    the parent process (and offers 1 or 4 devices, not 8).  Rows it
+    returns are labelled with :func:`cpu_rows`."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    return subprocess.run(cmd, capture_output=True, text=True,
+                          timeout=timeout, env=env)
+
+
+def cpu_rows(rows: List[Dict]) -> List[Dict]:
+    """Label rows measured by a :func:`cpu_worker` child."""
+    return [dict(r, platform="cpu") for r in rows]
 
 
 def wall_time(fn: Callable, *args, iters: int = 5) -> float:

@@ -2,13 +2,13 @@
 
 Two views: the modeled single-device PAT-vs-TStream comparison (paper
 figure), plus **measured** fused sharded streaming rows across the same
-mp_ratio/mp_len grid on an 8-device shared-nothing mesh (subprocess
-worker; exchange drops accounted per row)."""
+mp_ratio/mp_len grid on an 8-device shared-nothing mesh (CPU subprocess
+worker, rows labelled ``platform: cpu``; exchange drops accounted per
+row)."""
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 import jax.numpy as jnp
@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.apps import ALL_APPS
 
-from .common import throughput_model
+from .common import cpu_rows, cpu_worker, throughput_model
 
 WIDTH = 40
 
@@ -24,10 +24,10 @@ WIDTH = 40
 def _sharded_rows(quick: bool):
     worker = os.path.join(os.path.dirname(__file__), "fig10_worker.py")
     cmd = [sys.executable, worker] + ([] if quick else ["--full"])
-    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=1800)
+    proc = cpu_worker(cmd, timeout=1800)
     if proc.returncode != 0:
         return [dict(fig="fig10", error=proc.stderr[-500:])]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return cpu_rows(json.loads(proc.stdout.strip().splitlines()[-1]))
 
 
 def run(quick: bool = True):

@@ -1,5 +1,7 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS", ""),
+    "--xla_force_host_platform_device_count=8"]))
 
 """Fig. 10 sharded worker (subprocess: 8 placeholder devices).
 
@@ -23,6 +25,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.apps import GS                                       # noqa: E402
 from repro.core.scheduler import DualModeEngine, EngineConfig   # noqa: E402
+from repro.core.sharded_stream import stream_mesh               # noqa: E402
 
 
 def main():
@@ -30,7 +33,7 @@ def main():
     n_events = 1024 if quick else 4096
     interval = 256
     n_partitions = 16
-    mesh = jax.make_mesh((8,), ("dev",))
+    mesh = stream_mesh((8,), ("dev",))
     store = GS.make_store()
     eng = DualModeEngine(GS, store, EngineConfig(), mesh=mesh,
                         layout="shared_nothing", exchange_slack=4.0)

@@ -1,5 +1,7 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS", ""),
+    "--xla_force_host_platform_device_count=8"]))
 
 """Fig. 14 worker (subprocess: needs 8 placeholder devices).
 
@@ -27,6 +29,7 @@ from repro.core.blotter import build_opbatch                    # noqa: E402
 from repro.core.engines import evaluate                         # noqa: E402
 from repro.core.scheduler import DualModeEngine, EngineConfig   # noqa: E402
 from repro.core.sharded import LAYOUTS, evaluate_sharded        # noqa: E402
+from repro.core.sharded_stream import stream_mesh               # noqa: E402
 from repro.launch.hlo_analysis import analyze_hlo               # noqa: E402
 
 
@@ -138,7 +141,7 @@ def _reshard_run(app, store, mesh, spec, slack, elastic):
 def main_reshard(size):
     from repro.apps import GS
     spec = RESHARD_SIZES["smoke" if size == "smoke" else "full"]
-    mesh = jax.make_mesh((8,), ("dev",))
+    mesh = stream_mesh((8,), ("dev",))
     store = GS.make_store()
     lean = spec["lean"]
     rows = []
@@ -150,7 +153,7 @@ def main_reshard(size):
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("socket", "core"))
+    mesh = stream_mesh((2, 4), ("socket", "core"))
     rng = np.random.default_rng(14)
     store = GS.make_store()
 

@@ -5,10 +5,10 @@ dry-run JSONs (results/dryrun/*.json).
   memory     = HLO_bytes_per_device / HBM_bw
   collective = wire_bytes_per_device / link_bw
 
-Peaks come from a per-``device_kind`` table (``DEVICE_PEAKS``) resolved
-against the running backend by default — the old hardcoded TPU-v5e
-constants silently mispriced every other host, including the CPU CI
-boxes.  Any entry can be overridden from the CLI
+Peaks come from the per-``device_kind`` table ``DEVICE_PEAKS``
+(``runtime/telemetry.py``), resolved against the record's
+``device_kind`` or the running backend; a kind not in the table raises.
+Any entry can be overridden from the CLI
 (``--peak-flops/--hbm-bw/--link-bw``) or per call via ``device_peaks``.
 
 HLO_FLOPs/bytes are trip-count-weighted per-device figures (see
@@ -23,26 +23,12 @@ import json
 import os
 from typing import Dict, List, Optional
 
-# peak (FLOP/s, HBM bytes/s, per-link bytes/s) by device kind.  Keys are
-# matched case-insensitively by prefix (``"tpu v5"`` covers
-# ``"TPU v5e"``/``"TPU v5p"`` unless a longer key matches first), with
-# "cpu" as the fallback row for hosts.  Sources: public TPU spec sheets;
-# the cpu row is a deliberately modest desktop-class estimate (AVX2 f32,
-# dual-channel DDR4, inter-socket UPI) so host rooflines stay meaningful
-# rather than absurdly compute-bound.
-DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
-    "tpu v4":  dict(peak_flops=275e12, hbm_bw=1228e9, link_bw=50e9),
-    "tpu v5e": dict(peak_flops=197e12, hbm_bw=819e9,  link_bw=50e9),
-    "tpu v5p": dict(peak_flops=459e12, hbm_bw=2765e9, link_bw=100e9),
-    "tpu v6":  dict(peak_flops=918e12, hbm_bw=1640e9, link_bw=100e9),
-    "cpu":     dict(peak_flops=1e12,   hbm_bw=40e9,   link_bw=20e9),
-}
+from repro.runtime.telemetry import DEVICE_PEAKS, device_peaks
 
-# legacy module constants (== the "tpu v5e" row, what the old hardcoded
-# numbers were) kept for direct importers
-PEAK_FLOPS = DEVICE_PEAKS["tpu v5e"]["peak_flops"]
-HBM_BW = DEVICE_PEAKS["tpu v5e"]["hbm_bw"]
-LINK_BW = DEVICE_PEAKS["tpu v5e"]["link_bw"]
+# legacy module constants: the TPU v5e row
+PEAK_FLOPS = DEVICE_PEAKS["tpu v5 lite"]["peak_flops"]
+HBM_BW = DEVICE_PEAKS["tpu v5 lite"]["hbm_bw"]
+LINK_BW = DEVICE_PEAKS["tpu v5 lite"]["link_bw"]
 
 SHAPE_TOKENS = {
     "train_4k": 4096 * 256,
@@ -50,32 +36,6 @@ SHAPE_TOKENS = {
     "decode_32k": 128,        # one token per sequence
     "long_500k": 1,
 }
-
-
-def device_peaks(device_kind: Optional[str] = None,
-                 override: Optional[Dict[str, float]] = None
-                 ) -> Dict[str, float]:
-    """Resolve the peak row for ``device_kind`` (default: the running
-    backend's ``jax.devices()[0].device_kind``), longest prefix match,
-    "cpu" fallback; ``override`` keys replace resolved entries."""
-    if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = "cpu"
-    kind = str(device_kind).lower()
-    row = None
-    for key in sorted(DEVICE_PEAKS, key=len, reverse=True):
-        if kind.startswith(key) or key.startswith(kind):
-            row = dict(DEVICE_PEAKS[key])
-            break
-    if row is None:
-        row = dict(DEVICE_PEAKS["cpu"])
-    if override:
-        row.update({k: float(v) for k, v in override.items()
-                    if v is not None})
-    return row
 
 
 def model_flops(rec: dict) -> float:
@@ -100,9 +60,7 @@ def roofline_row(rec: dict,
     if rec.get("skipped") or rec.get("error"):
         return None
     if peaks is None:
-        # dry-run records carry the arch they were analyzed for; fall
-        # back to the running backend only when they don't
-        peaks = device_peaks(rec.get("device_kind") or rec.get("arch"))
+        peaks = device_peaks(rec.get("device_kind"))
     ndev = rec["n_devices"]
     t_comp = rec["hlo_flops"] / peaks["peak_flops"]
     t_mem = rec["hlo_bytes_written"] / peaks["hbm_bw"]
@@ -152,7 +110,7 @@ def main(argv=None):
     p.add_argument("--multi-pod", action="store_true")
     p.add_argument("--device-kind", default=None,
                    help="peak table row to price against (default: the "
-                        "record's arch, else the running backend)")
+                        "record's device_kind, else the running backend)")
     p.add_argument("--peak-flops", type=float, default=None)
     p.add_argument("--hbm-bw", type=float, default=None)
     p.add_argument("--link-bw", type=float, default=None)

@@ -28,6 +28,9 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     quick = not args.full
 
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
+
     from . import (adaptive_storm, fig8_throughput, fig9_breakdown,
                    fig10_multipartition, fig11_workload, fig12_interval,
                    fig13_latency, fig14_numa, fused_stream,

@@ -4,15 +4,17 @@ events/sec per chain-shard layout × device count for the owner-routed
 fused sharded ``run_stream``, against the single-device fused driver and
 the replicate-everything per-batch ``evaluate_sharded`` loop it replaces,
 plus per-layout collective bytes and exchange padding/drop accounting.
-Runs in a subprocess (needs an 8-device placeholder mesh); rows land in
-``BENCH_sharded_stream.json`` via ``benchmarks/run.py``.
+Runs in a CPU subprocess (needs an 8-device placeholder mesh; rows are
+labelled ``platform: cpu``); rows land in ``BENCH_sharded_stream.json``
+via ``benchmarks/run.py``.
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
+
+from .common import cpu_rows, cpu_worker
 
 
 def run(quick: bool = True, smoke: bool = False):
@@ -23,7 +25,7 @@ def run(quick: bool = True, smoke: bool = False):
         cmd.append("--smoke")
     elif not quick:
         cmd.append("--full")
-    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=3600)
+    proc = cpu_worker(cmd, timeout=3600)
     if proc.returncode != 0:
         return [dict(fig="sharded_stream", error=proc.stderr[-800:])]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return cpu_rows(json.loads(proc.stdout.strip().splitlines()[-1]))

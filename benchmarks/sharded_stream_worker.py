@@ -10,7 +10,9 @@ line; ``benchmarks/sharded_stream.py`` relays them into
 """
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS", ""),
+    "--xla_force_host_platform_device_count=8"]))
 
 import argparse
 import dataclasses
@@ -28,6 +30,7 @@ from repro.apps import ALL_APPS                                # noqa: E402
 from repro.core.blotter import build_opbatch                   # noqa: E402
 from repro.core.scheduler import DualModeEngine, EngineConfig  # noqa: E402
 from repro.core.sharded import evaluate_sharded                # noqa: E402
+from repro.core.sharded_stream import stream_mesh               # noqa: E402
 from repro.launch.hlo_analysis import analyze_hlo              # noqa: E402
 
 
@@ -91,16 +94,16 @@ def main():
 
     if args.smoke:
         n_events, interval, iters = 256, 64, 2
-        meshes = [(jax.make_mesh((8,), ("dev",)), 8, "1x8")]
+        meshes = [(stream_mesh((8,), ("dev",)), 8, "1x8")]
     elif args.full:
         n_events, interval, iters = 8192, 512, 7
-        meshes = [(jax.make_mesh((d,), ("dev",)), d, f"1x{d}")
+        meshes = [(stream_mesh((d,), ("dev",)), d, f"1x{d}")
                   for d in (2, 4, 8)]
     else:
         n_events, interval, iters = 2048, 512, 3
-        meshes = [(jax.make_mesh((d,), ("dev",)), d, f"1x{d}")
+        meshes = [(stream_mesh((d,), ("dev",)), d, f"1x{d}")
                   for d in (2, 8)]
-    mesh2 = jax.make_mesh((2, 4), ("socket", "core"))
+    mesh2 = stream_mesh((2, 4), ("socket", "core"))
 
     app = ALL_APPS["gs"]
     rng = np.random.default_rng(17)
@@ -139,7 +142,7 @@ def main():
             (n_events // interval, interval) + np.asarray(v).shape[1:]))
             for k, v in stream.items()}
         lowered = eng._sharded._impl.lower(
-            jnp.array(store.values, copy=True), batched, jnp.int32(0))
+            eng.carry_in(store.values), batched, jnp.int32(0))
         hlo = analyze_hlo(lowered.compile().as_text(), mesh.size)
         rows.append(dict(
             fig="sharded_stream", app="gs", layout=layout,

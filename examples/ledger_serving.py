@@ -8,10 +8,12 @@ effects) and conservation of money under the dual-mode engine.
 import numpy as np
 
 from repro.apps import SL
+from repro.compile_cache import setup_compile_cache
 from repro.core import DualModeEngine, EngineConfig
 
 
 def main():
+    setup_compile_cache()
     rng = np.random.default_rng(7)
     stream = SL.gen_events(rng, 3000)
     store = SL.make_store()

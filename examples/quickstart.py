@@ -9,6 +9,7 @@ sequential oracle.
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.core import AppSpec, DualModeEngine, EngineConfig, make_store
 from repro.core.types import ASSOC_FUNS
 
@@ -34,6 +35,7 @@ def make_app():
 
 
 def main():
+    setup_compile_cache()
     app = make_app()
     store = app.make_store()
     rng = np.random.default_rng(0)

@@ -68,16 +68,18 @@ ap.add_argument("--hlo-cost", action="store_true",
                      "compiled-HLO flops/bytes + roofline fractions")
 args = ap.parse_args()
 if args.devices:
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.devices}")
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS", ""),
+        f"--xla_force_host_platform_device_count={args.devices}"]))
 
-import jax                      # noqa: E402  (after XLA_FLAGS)
-import numpy as np              # noqa: E402
+import numpy as np              # noqa: E402  (after XLA_FLAGS)
 
 from repro.apps import ALL_APPS                                # noqa: E402
+from repro.compile_cache import setup_compile_cache             # noqa: E402
 from repro.core.intervals import (PhasedReplaySource, ReplaySource,
                                   WatermarkPolicy)              # noqa: E402
 from repro.core.scheduler import DualModeEngine, EngineConfig   # noqa: E402
+from repro.core.sharded_stream import stream_mesh               # noqa: E402
 from repro.runtime.controller import ControllerConfig           # noqa: E402
 from repro.runtime.faults import corrupt_snapshot               # noqa: E402
 from repro.runtime.service import ServiceConfig, StreamService  # noqa: E402
@@ -93,6 +95,7 @@ def outputs_identical(a_list, b_list):
 
 
 def main():
+    setup_compile_cache()
     app = ALL_APPS["gs"]
     store = app.make_store()
     iv = args.interval
@@ -117,8 +120,7 @@ def main():
         mk = lambda: ReplaySource(app.gen_events, n_events, seed=42,
                                   arrival_batch=max(1, iv // 4),
                                   jitter=args.jitter)
-    mesh = (jax.make_mesh((args.devices,), ("dev",)) if args.devices
-            else None)
+    mesh = stream_mesh((args.devices,), ("dev",)) if args.devices else None
     # storm: start the sharded exchange starved (slack 1.5) so the
     # controller's widening decisions actually have work to do
     eng = DualModeEngine(app, store, EngineConfig(scheme="tstream"),

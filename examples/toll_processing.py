@@ -10,10 +10,12 @@ import time
 import numpy as np
 
 from repro.apps import TP
+from repro.compile_cache import setup_compile_cache
 from repro.core import DualModeEngine, EngineConfig
 
 
 def main():
+    setup_compile_cache()
     rng = np.random.default_rng(42)
     stream = TP.gen_events(rng, 2000)
     store = TP.make_store()

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.configs import get_arch
 from repro.data import PipelineConfig, StreamingPipeline
 from repro.models import init_params, loss_fn
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--ckpt-dir", default="results/ckpt_100m")
     args = ap.parse_args()
+    setup_compile_cache()
 
     # ~100M params: 8 layers, d=768, ffn 3072, vocab 32k
     base = get_arch("minicpm-2b")

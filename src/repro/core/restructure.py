@@ -103,7 +103,7 @@ def partition_fits(n_rows: int, n_buckets: int) -> bool:
 
 def megakernel_engaged(n_rows: int, n_slots_incl_pad: int, *,
                        method: str, has_max: bool,
-                       funs_simple: bool) -> bool:
+                       funs_simple: bool, use_pallas: bool = False) -> bool:
     """Whether the fused drivers evaluate chains through the fused
     partition→segscan→commit megakernel (``kernels/megakernel``).
 
@@ -113,7 +113,10 @@ def megakernel_engaged(n_rows: int, n_slots_incl_pad: int, *,
     neutrals break) — then either an explicit ``method="megakernel"``
     force or, under "auto", the measured per-device win band
     (``kernels/autotune.MEGA_BOUNDS``).  Ineligible forces fall back to
-    the staged path (bit-identical by construction), logged once.
+    the staged path (bit-identical by construction), logged once.  With
+    ``use_pallas``, "auto" also needs the kernel to hold the interval
+    (``mega_kernel_fits``): the band never engages a rung whose kernel
+    would give way to its XLA reference.
     """
     eligible = (not has_max) and funs_simple
     if method == "megakernel":
@@ -124,6 +127,10 @@ def megakernel_engaged(n_rows: int, n_slots_incl_pad: int, *,
         return False
     band = autotune.mega_bounds()
     min_rows = band.get("min_rows")
+    if use_pallas:
+        from repro.kernels.megakernel import mega_kernel_fits
+        if not mega_kernel_fits(n_rows, n_slots_incl_pad):
+            return False
     return (min_rows is not None and int(n_rows) >= int(min_rows)
             and n_slots_incl_pad <= int(band.get("max_buckets", 0)))
 

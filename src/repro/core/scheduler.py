@@ -38,7 +38,8 @@ from .blotter import AppSpec, build_opbatch
 from .engines import (CHAIN_SCHEMES, EngineStats, evaluate,
                       simple_affine_luts, tstream_scan_coefs_stream,
                       tstream_scan_execute, tstream_scan_plan)
-from .restructure import megakernel_engaged, restructure, restructure_stream
+from .restructure import (megakernel_engaged, restructure, restructure_path,
+                          restructure_stream)
 from .types import OpResults, StateStore
 
 
@@ -228,6 +229,42 @@ class DualModeEngine:
         res_all, ebs_all, values, est = fn(values, batched, jnp.int32(ts0))
         return res_all, ebs_all, values, dict(engine=est)
 
+    def pallas_kernels(self, interval: int) -> Tuple[str, ...]:
+        """Names of the Pallas kernels (each ``pallas_call``'s ``name``)
+        the single-device fused chunk program dispatches for
+        ``interval``-event intervals: the restructure rung that
+        ``cfg.restructure_method`` resolves to, and which of its stages
+        run a kernel rather than the XLA reference.  Empty without
+        ``use_pallas``.  Mirrors ``_fused_impl``'s dispatch, so a compiled
+        chunk program can be checked against it."""
+        from repro.kernels.megakernel import mega_kernel_fits
+        from repro.kernels.radix_partition.ops import kernel_fits
+        app, cfg, store = self.app, self.cfg, self.init_store
+        if not cfg.use_pallas or cfg.scheme not in CHAIN_SCHEMES:
+            return ()
+        n = interval * app.max_ops
+        n_slots = store.values.shape[0]
+        has_max = any(store.table_is_max)
+        radix = ("radix_partition",) if kernel_fits(n_slots, n) else ()
+        assoc = _assoc_fast(app, cfg)
+        if assoc and megakernel_engaged(
+                n, n_slots, method=cfg.restructure_method, has_max=has_max,
+                funs_simple=simple_affine_luts(app.funs) is not None,
+                use_pallas=True):
+            return radix + (("fused_chain",)
+                            if mega_kernel_fits(n, n_slots) else ())
+        path = restructure_path(n, store.pad_uid, rowmajor_ts=True,
+                                method=cfg.restructure_method)
+        kernels = radix if path == "partition" else ()
+        # engines.evaluate's segmented-scan path (the fast path, or the
+        # per-interval scan under abort repass)
+        if assoc or cfg.scheme == "tstream_scan" or (
+                cfg.scheme == "tstream" and app.associative_only
+                and not app.has_gates):
+            kernels += ("segscan_affine",) + (("segscan_max",)
+                                              if has_max else ())
+        return kernels
+
     def post_outputs(self, res_all, ebs_all, n_intervals: int):
         """Materialize a chunk's per-interval outputs (blocks on D2H)."""
         return self._outs(res_all, ebs_all, n_intervals)
@@ -390,6 +427,13 @@ def _step_impl(store: StateStore, events, ts_base, *, app: AppSpec,
     return res, ebs, values, stats
 
 
+def _assoc_fast(app: AppSpec, cfg: EngineConfig) -> bool:
+    """Whether the fused chunk program takes the associative fast path."""
+    return (cfg.scheme in ("tstream", "tstream_scan")
+            and app.associative_only
+            and not (cfg.abort_repass and app.may_abort))
+
+
 def _fused_impl(values, events_b, ts0, *, app: AppSpec, cfg: EngineConfig,
                 store: StateStore):
     """Whole-stream driver: one jitted call, ``lax.scan`` over intervals.
@@ -410,9 +454,7 @@ def _fused_impl(values, events_b, ts0, *, app: AppSpec, cfg: EngineConfig,
     ops_all, ebs_all = jax.vmap(
         lambda ev, tb: build_opbatch(app, store, ev, tb))(events_b, ts_bases)
 
-    assoc_fast = (cfg.scheme in ("tstream", "tstream_scan")
-                  and app.associative_only
-                  and not (cfg.abort_repass and app.may_abort))
+    assoc_fast = _assoc_fast(app, cfg)
 
     # Pallas fast path: lane-pad operands & state to the kernel width ONCE
     # per stream, so per-interval kernel dispatch does no lane padding.
@@ -471,7 +513,8 @@ def _fused_assoc(store: StateStore, ops_all, *, app: AppSpec,
     if megakernel_engaged(ops_all.uid.shape[-1], store.values.shape[0],
                           method=cfg.restructure_method,
                           has_max=any(store.table_is_max),
-                          funs_simple=luts is not None):
+                          funs_simple=luts is not None,
+                          use_pallas=cfg.use_pallas):
         return _fused_assoc_mega(store, ops_all, luts=luts, cfg=cfg)
 
     pres_all = restructure_stream(

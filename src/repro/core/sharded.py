@@ -69,8 +69,6 @@ def evaluate_sharded(store: StateStore, ops: OpBatch,
     — row order is (ts, slot).
     """
     assert layout in LAYOUTS, layout
-    from jax.experimental.shard_map import shard_map
-
     n_dev = mesh.size
     axes = mesh.axis_names
     n_sockets = mesh.shape.get("socket", 1)
@@ -116,9 +114,9 @@ def evaluate_sharded(store: StateStore, ops: OpBatch,
             return _eval_local(vals_local, lops,
                                sim_local if has_max else None, funs)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(axes), P(axes), P()), out_specs=P(axes),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(axes), P(axes), P()),
+                           out_specs=P(axes), check_vma=False)
         out_blocks = fn(blocked(values, n_dev, 0.0),
                         blocked(sim, n_dev, False), rops)
         out = unblocked(out_blocks, n_dev)
@@ -147,9 +145,9 @@ def evaluate_sharded(store: StateStore, ops: OpBatch,
             # socket block across cores) — see ownership.chunk_shard_output
             return chunk_shard_output(merged, core, n_core)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(axes[0]), P(axes[0]), P()),
-                       out_specs=P(axes), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(axes[0]), P(axes[0]), P()),
+                           out_specs=P(axes), check_vma=False)
         out_chunks = fn(blocked(values, n_sockets, 0.0),
                         blocked(sim, n_sockets, False), rops)
         out = unchunk_output(out_chunks, n_sockets, per).reshape(s_pad, width)
@@ -166,8 +164,8 @@ def evaluate_sharded(store: StateStore, ops: OpBatch,
         delta = new_vals - vals
         merged = vals + jax.lax.psum(delta, axes)       # global merge
         return chunk_shard_output(merged, dev, n_dev)
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=P(axes),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=P(axes), check_vma=False)
     out = fn(values, rops)
     out = unchunk_output(out, 1, s_pad + 1).reshape(s_pad + 1, width)
     return unpermute_values(own, out)[:-1]

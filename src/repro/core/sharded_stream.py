@@ -55,6 +55,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from .blotter import AppSpec, build_opbatch
@@ -74,6 +75,21 @@ log = logging.getLogger(__name__)
 _INF = jnp.int32(10 ** 6)
 
 
+def stream_mesh(shape, axis_names, *, devices=None):
+    """The device mesh ``ShardedStream`` runs on.
+
+    Every axis is ``Auto``: ``ShardedStream`` places data itself through
+    ``shard_map`` specs, and its host-side gathers (``_sharded_blocks_impl``,
+    the ownership tables) leave sharding propagation to XLA.
+    ``jax.make_mesh`` without ``axis_types`` makes *Explicit* axes, under
+    which those gathers are refused at trace time.
+    """
+    shape, axis_names = tuple(shape), tuple(axis_names)
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
+
+
 def _bool_pmax(x: jnp.ndarray, axes) -> jnp.ndarray:
     return jax.lax.pmax(x.astype(jnp.int32), axes) > 0
 
@@ -89,6 +105,11 @@ class ShardedStream:
     def __init__(self, app: AppSpec, store: StateStore, cfg, mesh,
                  layout: str = "shared_nothing", exchange_slack: float = 2.0):
         assert layout in LAYOUTS, layout
+        if any(t != AxisType.Auto for t in mesh.axis_types):
+            raise ValueError(
+                f"sharded run_stream needs a mesh with Auto axes (got "
+                f"{mesh.axis_types}); build it with "
+                f"core.sharded_stream.stream_mesh")
         if cfg.scheme not in ("tstream", "tstream_scan", "tstream_lockstep",
                               "mvlk"):
             raise ValueError(
@@ -340,8 +361,6 @@ def _from_blocks_impl(blocks, *, eng: ShardedStream):
 
 
 def _sharded_blocks_impl(blocks, events_b, ts0, *, eng: ShardedStream):
-    from jax.experimental.shard_map import shard_map
-
     app, cfg, own, layout = eng.app, eng.cfg, eng.own, eng.layout
     mesh, axes = eng.mesh, eng.axes
     n_dev, n_route = eng.n_dev, eng.n_route
@@ -376,12 +395,12 @@ def _sharded_blocks_impl(blocks, events_b, ts0, *, eng: ShardedStream):
     # specs are pytree prefixes: one spec covers a whole output subtree;
     # every spec mentions every mesh axis (see the chunk-sharding note at
     # the end of _stream_body)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_spec, state_spec, P(None, axes)),
         out_specs=(P(None, axes), P(None, axes), P(axes), P(axes), P(axes),
                    P(axes), P(axes)),
-        check_rep=False)
+        check_vma=False)
     (res_all, ebs_all, blocks_out, dropped, shipped, fills,
      loads) = fn(blocks, sim_b, events_b)
     dropped = jnp.sum(dropped, axis=0)                    # [n_intervals]
@@ -439,13 +458,11 @@ def _migrate_impl(blocks, dstv, nidxv, *, eng: ShardedStream, cap: int):
     owner's block.  ``cap`` is the exact max moved-rows count between any
     device pair, so the exchange never drops (zero loss by construction).
     """
-    from jax.experimental.shard_map import shard_map
-
     axes, n_dev, per = eng.axes, eng.n_dev, eng.own.per
     body = partial(_migrate_body, axes=axes, n_dev=n_dev, per=per, cap=cap)
-    fn = shard_map(body, mesh=eng.mesh,
-                   in_specs=(P(axes), P(axes), P(axes)),
-                   out_specs=(P(axes), P(axes)), check_rep=False)
+    fn = jax.shard_map(body, mesh=eng.mesh,
+                       in_specs=(P(axes), P(axes), P(axes)),
+                       out_specs=(P(axes), P(axes)), check_vma=False)
     blocks, moved = fn(blocks, dstv, nidxv)
     return blocks, jnp.sum(moved)
 
@@ -592,7 +609,8 @@ def _stream_body(blocks, sim_b, events_loc, *, eng: ShardedStream, dims,
         mega_luts = simple_affine_luts(app.funs)
         if megakernel_engaged(R, lpad + 1, method=cfg.restructure_method,
                               has_max=has_max,
-                              funs_simple=mega_luts is not None):
+                              funs_simple=mega_luts is not None,
+                              use_pallas=cfg.use_pallas):
             # megakernel rung: a light geometry-free partition plan, then
             # ONE fused dispatch per interval replaces the staged
             # plan → coefs → execute pipeline (bit-identical — see

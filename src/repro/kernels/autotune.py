@@ -20,13 +20,16 @@ module makes the block parameters a function of the device:
    — every CPU host, and CI's ``JAX_PALLAS_INTERPRET=1`` runs) timing a
    Python emulation is meaningless, so the decision is the deterministic
    default candidate, recorded with ``source="interpret-default"``.
+   Nothing is timed under a trace either: the ops wrappers resolve their
+   block while ``jax.jit`` traces them, when no kernel can run, so such a
+   decision is the default candidate with ``source="default"``.
 3. **Caching** — winners live in an in-process dict keyed by
    ``(kernel, shape_bucket, dtype, device_kind)``; set
    ``REPRO_AUTOTUNE_CACHE=/path.json`` to also round-trip decisions
    through an on-disk JSON cache (loaded lazily, written after every new
-   decision).  Decisions are deterministic given a cache: the same key
-   never re-benchmarks in one process or across processes sharing the
-   disk cache.
+   measured decision; defaults are never written).  Decisions are
+   deterministic given a cache: the same key never re-benchmarks in one
+   process or across processes sharing the disk cache.
 4. **Logging** — every decision is logged exactly once per process per
    key (and appended to ``REPRO_AUTOTUNE_LOG`` as JSON lines when set —
    CI uploads that file as a build artifact).
@@ -78,10 +81,10 @@ LANES = 128  # TPU register lane width — all kernels pad lanes to this
 # used).
 LADDER_BOUNDS: Dict[str, Tuple[int, int]] = {
     "cpu": (16, 1 << 18),
-    "tpu v3": (64, 1 << 16),
     "tpu v4": (64, 1 << 16),
+    "tpu v5 lite": (64, 1 << 16),
     "tpu v5": (64, 1 << 16),
-    "tpu v6": (64, 1 << 16),
+    "tpu v6 lite": (64, 1 << 16),
 }
 
 # Fused megakernel auto band, per device kind:
@@ -106,8 +109,9 @@ LADDER_BOUNDS: Dict[str, Tuple[int, int]] = {
 MEGA_BOUNDS: Dict[str, Dict] = {
     "cpu": dict(min_rows=1 << 15, max_buckets=1 << 14),
     "tpu v4": dict(min_rows=1 << 12, max_buckets=1 << 14),
+    "tpu v5 lite": dict(min_rows=1 << 12, max_buckets=1 << 14),
     "tpu v5": dict(min_rows=1 << 12, max_buckets=1 << 14),
-    "tpu v6": dict(min_rows=1 << 12, max_buckets=1 << 14),
+    "tpu v6 lite": dict(min_rows=1 << 12, max_buckets=1 << 14),
 }
 
 
@@ -118,12 +122,14 @@ def _canon_kind(device_kind: Optional[str]) -> str:
 
 
 def _table_row(table: Dict[str, object], kind: str):
-    if kind in table:
-        return table[kind]
-    for k, v in table.items():  # prefix match: "tpu v5" covers "TPU v5e"
-        if k != "cpu" and kind.startswith(k):
-            return v
-    return table["cpu"]
+    """The row for ``kind`` (tables are keyed by the lower-cased
+    ``device_kind`` JAX reports; a v5e reports "TPU v5 lite").  A kind
+    missing from the table raises: another device's row is not a bound
+    for this one."""
+    if kind not in table:
+        raise KeyError(f"no autotune row for device_kind {kind!r}; known "
+                       f"kinds: {sorted(table)}")
+    return table[kind]
 
 
 def ladder_bounds(device_kind: Optional[str] = None) -> Tuple[int, int]:
@@ -207,7 +213,7 @@ class Decision:
     dtype: str
     device_kind: str
     param: int
-    source: str            # interpret-default | microbench | forced | disk
+    source: str  # interpret-default | default | microbench | forced | disk
     candidates: Tuple[int, ...] = ()
     timings_us: Optional[Dict[str, float]] = None
 
@@ -220,6 +226,7 @@ _CACHE: Dict[Tuple[str, str, str, str], Decision] = {}
 _LOGGED: set = set()
 _DISK_LOADED: set = set()  # cache paths already read this process
 
+_MEASURED = ("microbench", "disk")  # the sources the disk cache keeps
 _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 _LOG_ENV = "REPRO_AUTOTUNE_LOG"
 
@@ -282,11 +289,20 @@ def _save_disk(path: str) -> None:
     try:
         tmp = path + ".tmp"
         with open(tmp, "w") as f:
-            json.dump(dict(decisions=[dataclasses.asdict(d)
-                                      for d in _CACHE.values()]), f, indent=2)
+            json.dump(dict(decisions=[
+                dataclasses.asdict(d) for d in _CACHE.values()
+                if d.source in _MEASURED]), f, indent=2)
         os.replace(tmp, path)
     except OSError as e:
         log.warning("autotune: cannot write cache %s: %s", path, e)
+
+
+def _tracing() -> bool:
+    """Whether a ``jax.jit``/``vmap`` trace is being built: a new array is
+    then a tracer, and ``block_until_ready`` on it returns at once — a
+    timing taken now would measure tracing, not the device."""
+    import jax.numpy as jnp
+    return isinstance(jnp.zeros(()), jax.core.Tracer)
 
 
 def decisions_log() -> list:
@@ -323,8 +339,9 @@ def decide(kernel: str, n: int, *, dtype: str = "float32",
 
     Resolution order: ``force`` (no cache interaction, logged once) ->
     in-process cache -> on-disk cache -> microbenchmark (compiled
-    backends with a ``bench_fn``) or the deterministic default candidate
-    (interpret mode / no bench_fn).
+    backends with a ``bench_fn``, outside any trace) or the deterministic
+    default candidate (interpret mode / no bench_fn / under a trace).
+    Only microbenchmarked decisions are written to the disk cache.
     """
     kind = _canon_kind(device_kind)
     if force is not None:
@@ -346,16 +363,17 @@ def decide(kernel: str, n: int, *, dtype: str = "float32",
 
     cands = candidates(kernel)
     interp = default_interpret() if interpret is None else interpret
-    if interp or bench_fn is None:
+    if interp or bench_fn is None or _tracing():
         d = Decision(kernel=kernel, shape_bucket=key[1], dtype=dtype,
                      device_kind=kind, param=cands[0],
                      source="interpret-default" if interp else "default",
                      candidates=cands)
-    else:
-        winner, timings = _microbench(cands, bench_fn)
-        d = Decision(kernel=kernel, shape_bucket=key[1], dtype=dtype,
-                     device_kind=kind, param=winner, source="microbench",
-                     candidates=cands, timings_us=timings)
+        _record(d)
+        return d
+    winner, timings = _microbench(cands, bench_fn)
+    d = Decision(kernel=kernel, shape_bucket=key[1], dtype=dtype,
+                 device_kind=kind, param=winner, source="microbench",
+                 candidates=cands, timings_us=timings)
     _record(d)
     if path:
         _save_disk(path)
@@ -418,8 +436,8 @@ def block_rows(kernel: str, n: int, *, force: Optional[int] = None,
     """The kernel-facing lookup: tuned block parameter for an ``n``-row
     dispatch (called by the ops wrappers at trace time — the result is a
     static argument of the inner ``pallas_call``)."""
-    return decide(kernel, n, dtype=dtype, force=force,
-                  bench_fn=_default_bench(kernel, n)).param
+    bench = None if _tracing() else _default_bench(kernel, n)
+    return decide(kernel, n, dtype=dtype, force=force, bench_fn=bench).param
 
 
 def main() -> None:  # pragma: no cover - CLI artifact helper

@@ -35,28 +35,37 @@ def bucket_of(key: jnp.ndarray, n_buckets: int) -> jnp.ndarray:
     return (h % jnp.uint32(n_buckets)).astype(jnp.int32)
 
 
-def _probe_kernel(q_ref, tlo_ref, thi_ref, out_ref, *, n_buckets: int):
+def _probe_kernel(q_ref, base_ref, tlo_ref, thi_ref, out_ref, *,
+                  n_buckets: int):
     q = q_ref[...]                       # [BLK, 1] i32 query keys
-    qk = q[:, 0]
-    qlo = (qk & 0xFFFF).astype(jnp.float32)[:, None]        # [BLK, 1]
-    qhi = ((qk >> 16) & 0xFFFF).astype(jnp.float32)[:, None]
+    blk = q.shape[0]
+    qlo = (q & 0xFFFF).astype(jnp.float32)                  # [BLK, 1]
+    qhi = ((q >> 16) & 0xFFFF).astype(jnp.float32)
     tlo = tlo_ref[...]                   # [n_buckets, ASSOC] f32 halves
     thi = thi_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (blk, ASSOC), 1).astype(
+        jnp.float32)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (blk, n_buckets), 1)
 
-    base = bucket_of(qk, n_buckets)      # [BLK]
-    found_slot = jnp.full((q.shape[0],), -1, jnp.int32)
+    base = base_ref[...]                 # [BLK, 1] i32 home bucket
+    found_slot = jnp.full((blk, 1), -1, jnp.int32)
     for p in range(MAX_PROBES):
-        bkt = (base + p) % n_buckets
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], n_buckets), 1)
-                  == bkt[:, None]).astype(jnp.float32)
-        cand_lo = jnp.dot(onehot, tlo)   # [BLK, ASSOC] exact 16-bit values
-        cand_hi = jnp.dot(onehot, thi)
-        match = (cand_lo == qlo) & (cand_hi == qhi)
-        lane = jnp.argmax(match, axis=1).astype(jnp.int32)
-        hit = jnp.any(match, axis=1)
-        slot = bkt * ASSOC + lane
+        bkt = base + p
+        bkt = jnp.where(bkt >= n_buckets, bkt - n_buckets, bkt)
+        onehot = (iota == bkt).astype(jnp.float32)
+        # HIGHEST: the MXU's default bf16 pass would round 16-bit halves
+        cand_lo = jnp.dot(onehot, tlo, preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
+        cand_hi = jnp.dot(onehot, thi, preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
+        match = (cand_lo == qlo) & (cand_hi == qhi)        # [BLK, ASSOC]
+        # first matching lane as an f32 min (Mosaic reduces f32 only)
+        first = jnp.min(jnp.where(match, lane, float(ASSOC)), axis=1,
+                        keepdims=True)
+        hit = first < float(ASSOC)
+        slot = bkt * ASSOC + first.astype(jnp.int32)
         found_slot = jnp.where((found_slot < 0) & hit, slot, found_slot)
-    out_ref[...] = found_slot[:, None]
+    out_ref[...] = found_slot
 
 
 def hash_probe_pallas(keys: jnp.ndarray, table_lo: jnp.ndarray,
@@ -69,15 +78,17 @@ def hash_probe_pallas(keys: jnp.ndarray, table_lo: jnp.ndarray,
     n = keys.shape[0]
     n_buckets = table_lo.shape[0]
     assert n % block_q == 0 and table_lo.shape == (n_buckets, ASSOC)
+    assert n_buckets >= MAX_PROBES, n_buckets
     kernel = functools.partial(_probe_kernel, n_buckets=n_buckets)
+    qspec = pl.BlockSpec((block_q, 1), lambda g: (g, 0))
+    tspec = pl.BlockSpec((n_buckets, ASSOC), lambda g: (0, 0))
     out = pl.pallas_call(
         kernel,
         grid=(n // block_q,),
-        in_specs=[pl.BlockSpec((block_q, 1), lambda g: (g, 0)),
-                  pl.BlockSpec((n_buckets, ASSOC), lambda g: (0, 0)),
-                  pl.BlockSpec((n_buckets, ASSOC), lambda g: (0, 0))],
-        out_specs=pl.BlockSpec((block_q, 1), lambda g: (g, 0)),
+        in_specs=[qspec, qspec, tspec, tspec],
+        out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
         interpret=interpret,
-    )(keys[:, None], table_lo, table_hi)
+        name="hash_probe",
+    )(keys[:, None], bucket_of(keys, n_buckets)[:, None], table_lo, table_hi)
     return out[:, 0]

@@ -39,8 +39,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+# scoped VMEM for the single block: the v5e compiler asks 50.4 MiB at the
+# fit bounds (MEGA_MAX_ROWS=4096 rows x 1024 slots), above its default
+# scoped limit; a v5e core has 128 MiB
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
 def _shift_down(x: jnp.ndarray, d: int, fill) -> jnp.ndarray:
@@ -53,9 +58,10 @@ def _fused_chain_kernel(f_ref, asel_ref, bis_ref, valid_ref, uid_ref,
                         operand_ref, values_ref,
                         pre_ref, post_ref, acc_ref, *,
                         n_rows: int, n_slots_padded: int):
-    f = f_ref[...] > 0.0                       # [N, LANES] seg-start flags
+    fr = f_ref[...]                            # [N, LANES] f32 seg starts
+    f = fr > 0.0
     valid = valid_ref[...] > 0.0               # [N, 1]
-    uid = uid_ref[...][:, 0]                   # [N] i32 (sorted)
+    uid = uid_ref[...]                         # [N, 1] i32 (sorted)
 
     # -- stage 1: coefficient expansion (VMEM; replaces the [N, W] af/bf
     #    HBM arrays of the staged plan).  Invalid rows become identity.
@@ -66,16 +72,18 @@ def _fused_chain_kernel(f_ref, asel_ref, bis_ref, valid_ref, uid_ref,
 
     # -- stage 2: inclusive segmented affine scan — the exact operation
     #    sequence of core.restructure.segmented_scan_affine (shift fills
-    #    flag=True / a=1 / b=0 block at the array edge).
-    fi, a_inc, b_inc = f, a, b
+    #    flag=1 / a=1 / b=0 block at the array edge).  Flags stay f32
+    #    (OR = max): Mosaic cannot shift or concatenate i1 vectors.
+    fi, a_inc, b_inc = fr, a, b
     d = 1
     while d < n_rows:
         ap = _shift_down(a_inc, d, 1.0)
         bp = _shift_down(b_inc, d, 0.0)
-        fp = _shift_down(fi, d, True)
-        a_inc, b_inc = (jnp.where(fi, a_inc, a_inc * ap),
-                        jnp.where(fi, b_inc, a_inc * bp + b_inc))
-        fi = fi | fp
+        fp = _shift_down(fi, d, 1.0)
+        crossed = fi > 0.0
+        a_inc, b_inc = (jnp.where(crossed, a_inc, a_inc * ap),
+                        jnp.where(crossed, b_inc, a_inc * bp + b_inc))
+        fi = jnp.maximum(fi, fp)
         d *= 2
 
     # -- exclusive view: identity at row 0 and at segment starts.
@@ -90,7 +98,7 @@ def _fused_chain_kernel(f_ref, asel_ref, bis_ref, valid_ref, uid_ref,
     # -- stage 3: state gather as a one-hot matmul (exact for finite
     #    values; TPUs have no efficient random gather inside a kernel).
     iota = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_slots_padded), 1)
-    oh = (iota == uid[:, None]).astype(jnp.float32)        # [N, S]
+    oh = (iota == uid).astype(jnp.float32)                 # [N, S]
     v0 = jnp.dot(oh, values_ref[...],
                  preferred_element_type=jnp.float32,
                  precision=jax.lax.Precision.HIGHEST)      # [N, LANES]
@@ -103,7 +111,8 @@ def _fused_chain_kernel(f_ref, asel_ref, bis_ref, valid_ref, uid_ref,
     #    its uid's accumulator column via the transposed one-hot (padding
     #    rows are their own segments with uid=pad and post=v0[pad]=0, so
     #    they only add exact zeros).
-    seg_end = jnp.concatenate([f[1:], jnp.full((1, LANES), True)], axis=0)
+    seg_end = jnp.concatenate([fr[1:], jnp.ones((1, LANES), jnp.float32)],
+                              axis=0) > 0.0
     contrib = jnp.where(seg_end, post, 0.0)
     acc_ref[...] = jax.lax.dot_general(
         oh, contrib, (((0,), (0,)), ((), ())),
@@ -146,5 +155,8 @@ def fused_chain_pallas(flags: jnp.ndarray, a_sel: jnp.ndarray,
                    jax.ShapeDtypeStruct((n, LANES), jnp.float32),
                    jax.ShapeDtypeStruct((s, LANES), jnp.float32)],
         interpret=interpret,
+        name="fused_chain",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(flags, a_sel, b_is, valid, uid, operand, values)
     return pre, post, acc

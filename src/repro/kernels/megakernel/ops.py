@@ -11,9 +11,10 @@ same structural-fallback pattern as ``radix_partition.kernel_fits``.
 """
 from __future__ import annotations
 
+import dataclasses
+import logging
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from ..runtime import default_interpret
@@ -28,6 +29,10 @@ from .ref import fused_chain_eval_ref
 #                    [rows, n_slots_padded] f32 (4 MiB at 2^20 cells).
 MEGA_MAX_ROWS = 4096
 MEGA_MAX_CELLS = 1 << 22
+# EngineStats.path of a megakernel dispatch that the XLA reference served
+FALLBACK_PATH = "megakernel-xla-fallback"
+
+log = logging.getLogger(__name__)
 
 
 def _pad_rows(n: int) -> int:
@@ -98,5 +103,22 @@ def fused_chain_eval(values: jnp.ndarray, sops, ch, pad_uid: int, *,
             n_ops=n, scheme="tstream", path="megakernel")
         return res, new_values, stats
 
-    return fused_chain_eval_ref(values, sops, ch, pad_uid,
-                                a_lut=a_lut, b_lut=b_lut)
+    res, new_values, stats = fused_chain_eval_ref(values, sops, ch, pad_uid,
+                                                  a_lut=a_lut, b_lut=b_lut)
+    if use_pallas:
+        _count_fallback(n, s)
+        stats = dataclasses.replace(stats, path=FALLBACK_PATH)
+    return res, new_values, stats
+
+
+def _count_fallback(n_rows: int, n_slots: int) -> None:
+    """A megakernel dispatch asked for the kernel but the interval does
+    not fit it: counted (once per traced program) in the default telemetry
+    registry and logged, and the rung's stats report ``FALLBACK_PATH``."""
+    from repro.runtime.telemetry import get_default
+    get_default().event(
+        "kernels.mega_fallback",
+        "megakernel: %d rows x %d slots exceed the kernel's bounds "
+        "(MEGA_MAX_ROWS=%d, MEGA_MAX_CELLS=%d) — the XLA reference "
+        "evaluates this interval", n_rows, n_slots, MEGA_MAX_ROWS,
+        MEGA_MAX_CELLS, logger=log)

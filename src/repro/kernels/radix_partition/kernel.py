@@ -21,7 +21,8 @@ TPU mapping
 Keys are tiled into blocks of BLOCK_ROWS rows; the bucket axis is padded
 to a lane multiple.  Each grid step builds a one-hot ``[BLOCK_ROWS, K]``
 matrix (broadcasted-iota compare — the same MXU/VPU-friendly trick as
-``hash_probe``), takes its within-block exclusive column cumsum, adds
+``hash_probe``), takes its within-block exclusive column prefix sum (a
+strictly-lower-triangular matmul on the MXU), adds
 the running histogram carried in VMEM scratch across grid steps (the
 standard Pallas sequential-carry pattern, as in ``segscan``), and reads
 each row's rank back out of its own one-hot column by a masked row-sum.
@@ -31,7 +32,7 @@ The grid is ``(batch, n_blocks)``: the batch axis lets a whole stream of
 stacked intervals partition in one dispatch (the carry re-initializes at
 block 0 of every batch), without relying on vmap-of-pallas_call.
 
-VMEM per grid step: one-hot + cumsum ≈ 2 · BLOCK_ROWS · K · 4 B
+VMEM per grid step: one-hot + prefix ≈ 2 · BLOCK_ROWS · K · 4 B
 (BLOCK_ROWS=256, K=2048: 4 MiB ≪ 16 MiB); larger bucket counts fall back
 to the XLA counting path (``ref.py``), the next rung of the ladder.
 """
@@ -46,6 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 256
 LANES = 128
+SUBLANES = 8  # a block's second-minor dim is a multiple of this
 MAX_KERNEL_BUCKETS = 2048  # one-hot VMEM bound; beyond -> XLA counting ref
 MAX_KERNEL_ROWS = 1 << 24  # f32 carry exactness: ranks/counts < 2^24
 
@@ -59,20 +61,29 @@ def _radix_rank_kernel(k_ref, rank_ref, cnt_ref, hist_ref, *,
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    k = k_ref[...][:, 0]                               # [B] i32 keys
+    k = k_ref[...]                                     # [B, 1] i32 keys
     iota = jax.lax.broadcasted_iota(jnp.int32, (block_rows, n_buckets_padded),
                                     1)
-    oh = (iota == k[:, None]).astype(jnp.float32)      # [B, K] one-hot
-    ex = jnp.cumsum(oh, axis=0) - oh                   # within-block exclusive
-    carry = hist_ref[...]                              # [1, K] running hist
-    r = jnp.sum((ex + carry) * oh, axis=1)             # [B] rank (exact f32)
-    rank_ref[...] = r.astype(jnp.int32)[:, None]
+    oh = (iota == k).astype(jnp.float32)               # [B, K] one-hot
+    # within-block exclusive column prefix sum as a strictly-lower-
+    # triangular matmul (Mosaic has no cumsum lowering); 0/1 operands and
+    # counts < 2^24 keep it exact in f32 at HIGHEST precision
+    row = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 1)
+    lower = (col < row).astype(jnp.float32)
+    ex = jnp.dot(lower, oh, preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)
+    carry = hist_ref[0:1, :]                           # [1, K] running hist
+    r = jnp.sum((ex + carry) * oh, axis=1, keepdims=True)  # [B, 1] exact
+    rank_ref[...] = r.astype(jnp.int32)
 
     new_hist = carry + jnp.sum(oh, axis=0, keepdims=True)
-    hist_ref[...] = new_hist
+    hist_ref[...] = jnp.broadcast_to(new_hist, hist_ref.shape)
     # constant index map: the block stays resident and the last grid step
-    # of this batch leaves the total histogram
-    cnt_ref[...] = new_hist.astype(jnp.int32)
+    # of this batch leaves the total histogram (in all 8 sublane rows: a
+    # block must span 8 rows, row 0 is read back)
+    cnt_ref[...] = jnp.broadcast_to(new_hist.astype(jnp.int32),
+                                    cnt_ref.shape)
 
 
 def radix_partition_pallas(keys: jnp.ndarray, n_buckets_padded: int, *,
@@ -93,10 +104,15 @@ def radix_partition_pallas(keys: jnp.ndarray, n_buckets_padded: int, *,
         grid=(bn, n_blocks),
         in_specs=[kspec],
         out_specs=[kspec,
-                   pl.BlockSpec((1, n_buckets_padded), lambda b, t: (b, 0))],
+                   pl.BlockSpec((SUBLANES, n_buckets_padded),
+                                lambda b, t: (b, 0))],
         out_shape=[jax.ShapeDtypeStruct((bn * rows, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((bn, n_buckets_padded), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((1, n_buckets_padded), jnp.float32)],
+                   jax.ShapeDtypeStruct((bn * SUBLANES, n_buckets_padded),
+                                        jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((SUBLANES, n_buckets_padded),
+                                   jnp.float32)],
         interpret=interpret,
+        name="radix_partition",
     )(keys.reshape(bn * rows, 1))
+    counts = counts.reshape(bn, SUBLANES, n_buckets_padded)[:, 0]
     return rank[:, 0].reshape(bn, rows), counts

@@ -41,6 +41,18 @@ def _shift_down(x: jnp.ndarray, d: int, fill) -> jnp.ndarray:
     return jnp.concatenate([pad, x[:-d]], axis=0)
 
 
+def _before_first_start(flags: jnp.ndarray, block_rows: int) -> jnp.ndarray:
+    """Rows with no segment start at or above them in the block: those
+    continue the carry's segment.  An inclusive prefix-OR of the f32 flags
+    as a log-step shifted max (Mosaic has no cumsum lowering)."""
+    seen = flags
+    d = 1
+    while d < block_rows:
+        seen = jnp.maximum(seen, _shift_down(seen, d, 0.0))
+        d *= 2
+    return seen == 0.0
+
+
 def _segscan_affine_kernel(f_ref, a_ref, b_ref, oa_ref, ob_ref,
                            ca_ref, cb_ref, *, block_rows: int):
     """Exclusive segmented scan of affine maps, carry across blocks."""
@@ -51,24 +63,27 @@ def _segscan_affine_kernel(f_ref, a_ref, b_ref, oa_ref, ob_ref,
         ca_ref[...] = jnp.ones_like(ca_ref)
         cb_ref[...] = jnp.zeros_like(cb_ref)
 
-    f = f_ref[...] > 0.0          # [R, LANES] raw flags (seg starts)
+    fr = f_ref[...]               # [R, LANES] f32 seg-start flags (0/1)
+    f = fr > 0.0
     a = a_ref[...]
     b = b_ref[...]
 
     # --- inclusive segmented scan within the block (Hillis–Steele). ------
     # combine(L, R) = R if R's range already crossed a segment start,
     #                 else R∘L:  A = A_R·A_L,  B = A_R·B_L + B_R.
-    # The shift fill uses flag=True: the block boundary blocks combining;
-    # the carry is folded in afterwards.
-    fi, ai, bi = f, a, b
+    # The shift fill uses flag=1: the block boundary blocks combining;
+    # the carry is folded in afterwards.  Flags stay f32 (OR = max): Mosaic
+    # cannot shift or concatenate i1 vectors.
+    fi, ai, bi = fr, a, b
     d = 1
     while d < block_rows:
-        fL = _shift_down(fi, d, True)
+        fL = _shift_down(fi, d, 1.0)
         aL = _shift_down(ai, d, 1.0)
         bL = _shift_down(bi, d, 0.0)
-        na = jnp.where(fi, ai, ai * aL)
-        nb = jnp.where(fi, bi, ai * bL + bi)
-        fi, ai, bi = fi | fL, na, nb
+        crossed = fi > 0.0
+        na = jnp.where(crossed, ai, ai * aL)
+        nb = jnp.where(crossed, bi, ai * bL + bi)
+        fi, ai, bi = jnp.maximum(fi, fL), na, nb
         d *= 2
 
     # --- exclusive view: identity at row 0 and at segment starts. --------
@@ -78,15 +93,13 @@ def _segscan_affine_kernel(f_ref, a_ref, b_ref, oa_ref, ob_ref,
     be = jnp.where(f, jnp.zeros_like(be), be)
 
     # --- fold the running carry into rows before the first segment start.
-    fint = f.astype(jnp.float32)
-    seen = jnp.cumsum(fint, axis=0) - fint      # # seg starts strictly before
-    open_head = (seen == 0.0) & ~f              # row continues the carry's seg
+    open_head = _before_first_start(fr, block_rows)
     ca, cb = ca_ref[...], cb_ref[...]
     oa_ref[...] = jnp.where(open_head, ae * ca, ae)
     ob_ref[...] = jnp.where(open_head, ae * cb + be, be)
 
     # --- update carry with the block's last inclusive row. ---------------
-    any_flag = jnp.any(f, axis=0, keepdims=True)
+    any_flag = jnp.max(fr, axis=0, keepdims=True) > 0.0
     la, lb = ai[-1:], bi[-1:]
     ca_ref[...] = jnp.where(any_flag, la, la * ca)
     cb_ref[...] = jnp.where(any_flag, lb, la * cb + lb)
@@ -101,28 +114,27 @@ def _segscan_max_kernel(f_ref, m_ref, om_ref, cm_ref, *, block_rows: int):
     def _init():
         cm_ref[...] = jnp.full_like(cm_ref, neg)
 
-    f = f_ref[...] > 0.0
+    fr = f_ref[...]
+    f = fr > 0.0
     m = m_ref[...]
 
-    fi, mi = f, m
+    fi, mi = fr, m
     d = 1
     while d < block_rows:
-        fL = _shift_down(fi, d, True)
+        fL = _shift_down(fi, d, 1.0)
         mL = _shift_down(mi, d, neg)
-        mi = jnp.where(fi, mi, jnp.maximum(mi, mL))
-        fi = fi | fL
+        mi = jnp.where(fi > 0.0, mi, jnp.maximum(mi, mL))
+        fi = jnp.maximum(fi, fL)
         d *= 2
 
     me = _shift_down(mi, 1, neg)
     me = jnp.where(f, jnp.full_like(me, neg), me)
 
-    fint = f.astype(jnp.float32)
-    seen = jnp.cumsum(fint, axis=0) - fint
-    open_head = (seen == 0.0) & ~f
+    open_head = _before_first_start(fr, block_rows)
     cm = cm_ref[...]
     om_ref[...] = jnp.where(open_head, jnp.maximum(me, cm), me)
 
-    any_flag = jnp.any(f, axis=0, keepdims=True)
+    any_flag = jnp.max(fr, axis=0, keepdims=True) > 0.0
     lm = mi[-1:]
     cm_ref[...] = jnp.where(any_flag, lm, jnp.maximum(cm, lm))
 
@@ -145,6 +157,7 @@ def segscan_affine_pallas(flags: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((1, LANES), jnp.float32),
                         pltpu.VMEM((1, LANES), jnp.float32)],
         interpret=interpret,
+        name="segscan_affine",
     )(flags, a, b)
 
 
@@ -164,4 +177,5 @@ def segscan_max_pallas(flags: jnp.ndarray, m: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct(m.shape, m.dtype),
         scratch_shapes=[pltpu.VMEM((1, LANES), jnp.float32)],
         interpret=interpret,
+        name="segscan_max",
     )(flags, m)

@@ -708,9 +708,42 @@ class ChunkProfiler:
                         type(e).__name__, e)
 
 
-# modest host fallback when benchmarks/roofline.py is not importable
-# (scripts run outside the repo root); matches its "cpu" row
-_FALLBACK_PEAKS = dict(peak_flops=1e12, hbm_bw=40e9, link_bw=20e9)
+# Peak (bf16 FLOP/s, HBM bytes/s, bytes/s per inter-chip link) per device,
+# keyed by the lower-cased ``device_kind`` JAX reports (a v5e reports
+# "TPU v5 lite", a v6e "TPU v6 lite", a v5p "TPU v5").  TPU rows: Google
+# Cloud TPU documentation, system-architecture pages "TPU v4", "TPU v5e",
+# "TPU v5p" and "TPU v6e" (peak compute, HBM bandwidth, and ICI bandwidth
+# per chip divided over its links).  The cpu row prices CPU hosts only: a
+# modest desktop-class estimate (AVX2 f32, dual-channel DDR4, UPI) so host
+# rooflines stay finite.  A kind missing here is an error, never priced
+# with another row.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "tpu v4":      dict(peak_flops=275e12, hbm_bw=1200e9, link_bw=50e9),
+    "tpu v5 lite": dict(peak_flops=197e12, hbm_bw=819e9,  link_bw=50e9),
+    "tpu v5":      dict(peak_flops=459e12, hbm_bw=2765e9, link_bw=100e9),
+    "tpu v6 lite": dict(peak_flops=918e12, hbm_bw=1640e9, link_bw=112e9),
+    "cpu":         dict(peak_flops=1e12,   hbm_bw=40e9,   link_bw=20e9),
+}
+
+
+def device_peaks(device_kind: Optional[str] = None,
+                 override: Optional[Dict[str, float]] = None
+                 ) -> Dict[str, float]:
+    """The ``DEVICE_PEAKS`` row for ``device_kind`` (default: the running
+    backend's ``jax.devices()[0].device_kind``); ``override`` keys replace
+    resolved entries.  Raises ``KeyError`` for a kind not in the table."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    kind = str(device_kind).strip().lower()
+    if kind not in DEVICE_PEAKS:
+        raise KeyError(f"no peak row for device_kind {device_kind!r}; "
+                       f"known kinds: {sorted(DEVICE_PEAKS)}")
+    row = dict(DEVICE_PEAKS[kind])
+    if override:
+        row.update({k: float(v) for k, v in override.items()
+                    if v is not None})
+    return row
 
 
 class CostAttributor:
@@ -745,11 +778,7 @@ class CostAttributor:
 
     def peaks(self) -> Dict[str, float]:
         if self._peaks is None:
-            try:
-                from benchmarks.roofline import device_peaks
-                self._peaks = device_peaks()
-            except Exception:
-                self._peaks = dict(_FALLBACK_PEAKS)
+            self._peaks = device_peaks()
         return self._peaks
 
     def annotate(self, cost: Dict, dur_s: float) -> Dict:

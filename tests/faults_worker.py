@@ -31,11 +31,12 @@ import numpy as np
 from repro.apps import ALL_APPS                                 # noqa: E402
 from repro.core.intervals import ReplaySource, WatermarkPolicy  # noqa: E402
 from repro.core.scheduler import DualModeEngine, EngineConfig   # noqa: E402
+from repro.core.sharded_stream import stream_mesh               # noqa: E402
 from repro.runtime.faults import FaultPlane, random_schedule    # noqa: E402
 from repro.runtime.service import ServiceConfig, StreamService  # noqa: E402
 from repro.runtime.service import StragglerPolicy              # noqa: E402
 
-MESH = jax.make_mesh((8,), ("dev",))
+MESH = stream_mesh((8,), ("dev",))
 INTERVAL = 32
 JITTER = 4
 WM = WatermarkPolicy(allowed_lateness=JITTER)

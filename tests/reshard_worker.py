@@ -33,12 +33,13 @@ from repro.apps import ALL_APPS                                 # noqa: E402
 from repro.core.intervals import (PhasedReplaySource,           # noqa: E402
                                   WatermarkPolicy)
 from repro.core.scheduler import DualModeEngine, EngineConfig   # noqa: E402
+from repro.core.sharded_stream import stream_mesh               # noqa: E402
 from repro.runtime.controller import ControllerConfig           # noqa: E402
 from repro.runtime.faults import (RESHARD_APPLY, Fault,         # noqa: E402
                                   FaultPlane, InjectedCrashError)
 from repro.runtime.service import ServiceConfig, StreamService  # noqa: E402
 
-MESH = jax.make_mesh((8,), ("dev",))
+MESH = stream_mesh((8,), ("dev",))
 INTERVAL = 64
 JITTER = 4
 # reshard-only controller: every other knob's lattice is empty

@@ -22,9 +22,10 @@ import numpy as np
 
 from repro.apps import ALL_APPS                               # noqa: E402
 from repro.core.scheduler import DualModeEngine, EngineConfig  # noqa: E402
+from repro.core.sharded_stream import stream_mesh              # noqa: E402
 
-MESH1 = jax.make_mesh((8,), ("dev",))
-MESH2 = jax.make_mesh((2, 4), ("socket", "core"))
+MESH1 = stream_mesh((8,), ("dev",))
+MESH2 = stream_mesh((2, 4), ("socket", "core"))
 
 
 def bit_identical(app_name, layout, mesh, *, n_events=128, interval=32,

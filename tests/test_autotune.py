@@ -199,3 +199,40 @@ def test_engineconfig_pins_block_params_without_autotune(monkeypatch):
         for k in a:
             np.testing.assert_array_equal(np.asarray(a[k]),
                                           np.asarray(b[k]))
+
+
+def test_decide_under_jit_takes_default_and_never_persists(tmp_path):
+    """Inside a trace no kernel can run, so a timing would measure
+    tracing: the decision is the default candidate, never written to the
+    disk cache."""
+    import jax
+    import jax.numpy as jnp
+    path = tmp_path / "tune.json"
+    bench = make_bench({256: 5e-6, 128: 1e-6, 512: 5e-6, 1024: 5e-6})
+    seen = []
+
+    def traced(x):
+        seen.append(autotune.decide("segscan", 1 << 12, bench_fn=bench,
+                                    interpret=False, device_kind="testkind",
+                                    cache_path=str(path)))
+        return x
+
+    jax.jit(traced)(jnp.ones(3))
+    assert seen[0].source == "default"
+    assert seen[0].param == seen[0].candidates[0]
+    assert not bench.calls
+    assert not path.exists()
+    assert all(d["source"] != "microbench" for d in autotune.decisions_log())
+
+
+def test_device_tables_keyed_by_reported_kind():
+    # a v5e reports "TPU v5 lite"; prefixes of other kinds never match
+    assert (autotune.ladder_bounds("TPU v5 lite")
+            == autotune.LADDER_BOUNDS["tpu v5 lite"])
+    assert (autotune.mega_bounds("TPU v5 lite")
+            == autotune.MEGA_BOUNDS["tpu v5 lite"])
+    for kind in ("TPU v5e", "tpu v7x", "gpu"):
+        with pytest.raises(KeyError):
+            autotune.ladder_bounds(kind)
+        with pytest.raises(KeyError):
+            autotune.mega_bounds(kind)

@@ -135,8 +135,10 @@ if st is not None:
         assert plan.slack == BASE.slack * 2.0 ** n_esc
         assert ctl.esc_done <= cfg.max_escalations
         if sharded:
-            assert all(d["knob"] == "slack" for d in ctl.trace), \
-                "sharded lattice is slack-only"
+            # the sharded engine has no scheme/rung variants; its lattice
+            # is {exchange slack, chunk size} (reshard is off in this cfg)
+            assert all(d["knob"] in ("slack", "chunk") for d in ctl.trace), \
+                "sharded lattice is {slack, chunk}"
         else:
             assert all(d["knob"] != "slack" for d in ctl.trace)
 

@@ -108,3 +108,29 @@ def test_hash_probe_matches_ref_and_truth(n_keys, n_buckets):
     s_abs = np.asarray(hpops.hash_probe(jnp.asarray(absent), lo, hi,
                                         interpret=True))
     assert np.all(s_abs == -1)
+
+
+def test_interpret_forced_on_tpu_backend_warns(monkeypatch, caplog):
+    import logging
+
+    from repro.kernels import runtime as R
+    monkeypatch.setenv(R.INTERPRET_ENV, "1")
+    monkeypatch.setattr(R.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(R, "_warned", [])
+    with caplog.at_level(logging.WARNING, logger=R.__name__):
+        assert R.default_interpret() is True
+    assert "forces Pallas interpret mode on a TPU" in caplog.text
+    monkeypatch.delenv(R.INTERPRET_ENV)
+    assert R.default_interpret() is False
+
+
+def test_tpu_kernels_in_reads_named_custom_calls():
+    from repro.kernels.runtime import tpu_kernels_in
+    hlo = "\n".join([
+        '  %segscan_affine.3 = (f32[8,128]) custom-call(%a), '
+        'custom_call_target="tpu_custom_call", backend_config="x"',
+        '  ROOT %fused_chain = f32[8,128] custom-call(%b), '
+        'custom_call_target="tpu_custom_call"',
+        '  %sort.1 = f32[8] custom-call(%c), custom_call_target="Sort"',
+    ])
+    assert tpu_kernels_in(hlo) == {"segscan_affine": 1, "fused_chain": 1}

@@ -253,9 +253,10 @@ import json, sys
 import jax, numpy as np
 from repro.apps import ALL_APPS
 from repro.core.scheduler import DualModeEngine, EngineConfig
+from repro.core.sharded_stream import stream_mesh
 
 out = {}
-mesh = jax.make_mesh((8,), ("dev",))
+mesh = stream_mesh((8,), ("dev",))
 for layout in ("shared_nothing", "shared_everything"):
     app = ALL_APPS["gs"]
     rng = np.random.default_rng(11)
@@ -288,3 +289,59 @@ def test_sharded_megakernel_bit_identical():
     assert proc.returncode == 0, proc.stderr[-2000:]
     verdict = json.loads(proc.stdout.strip().splitlines()[-1])
     assert verdict == {"shared_nothing": True, "shared_everything": True}
+
+
+def test_auto_band_needs_kernel_fit_under_use_pallas():
+    """With use_pallas, "auto" never engages a rung whose kernel would
+    give way to the XLA reference (the band's row count is past the
+    kernel's single-block bound on this device)."""
+    from repro.kernels.autotune import mega_bounds
+    band = mega_bounds()
+    rows = band["min_rows"]
+    assert megakernel_engaged(rows, 128, method="auto", has_max=False,
+                              funs_simple=True)
+    assert (megakernel_engaged(rows, 128, method="auto", has_max=False,
+                               funs_simple=True, use_pallas=True)
+            == mega_kernel_fits(rows, 128))
+
+
+def test_forced_megakernel_fallback_is_counted_and_reported():
+    """A forced megakernel dispatch too large for the kernel runs the XLA
+    reference: counted in telemetry, and the rung's stats say so."""
+    import jax.numpy as jnp
+    from repro.kernels.megakernel.ops import FALLBACK_PATH
+    from repro.runtime.telemetry import get_default
+    app = ALL_APPS["gs"]
+    store = app.make_store()
+    interval = 48            # 480 rows x 10,001 slots > MEGA_MAX_CELLS
+    assert not mega_kernel_fits(interval * app.max_ops,
+                                store.values.shape[0])
+
+    def count():
+        return sum(e["count"] for e in get_default().snapshot()["events"]
+                   if e["name"] == "kernels.mega_fallback")
+
+    before = count()
+    eng = DualModeEngine(app, store, EngineConfig(
+        restructure_method="megakernel", use_pallas=True))
+    assert "fused_chain" not in eng.pallas_kernels(interval)
+    rng = np.random.default_rng(5)
+    batched = {k: jnp.asarray(v.reshape((2, interval) + v.shape[1:]))
+               for k, v in app.gen_events(rng, 2 * interval).items()}
+    _, _, _, st = eng.run_stream_chunk(jnp.array(store.values), batched, 0)
+    assert st["engine"].path == FALLBACK_PATH
+    assert count() > before
+
+
+@pytest.mark.parametrize("app_name,cfg,want", [
+    ("gs", dict(), ()),
+    ("gs", dict(use_pallas=True), ("segscan_affine",)),
+    ("tp", dict(use_pallas=True), ("segscan_affine", "segscan_max")),
+    ("tp", dict(use_pallas=True, restructure_method="partition"),
+     ("radix_partition", "segscan_affine", "segscan_max")),
+    ("sl", dict(use_pallas=True), ()),
+])
+def test_pallas_kernels_of_the_resolved_rung(app_name, cfg, want):
+    app = ALL_APPS[app_name]
+    eng = DualModeEngine(app, app.make_store(), EngineConfig(**cfg))
+    assert eng.pallas_kernels(500) == want

@@ -152,3 +152,24 @@ def test_segmented_scan_offset_invariant():
     A2, B2 = segmented_scan_affine(a2, b2, seg2)
     np.testing.assert_array_equal(np.asarray(A[5:11]), np.asarray(A2[3:9]))
     np.testing.assert_array_equal(np.asarray(B[5:11]), np.asarray(B2[3:9]))
+
+
+def test_stream_mesh_is_auto_and_explicit_mesh_is_refused():
+    """JAX's make_mesh defaults to Explicit axes, under which
+    ``ShardedStream``'s gathers are refused at trace time; it takes Auto
+    meshes (``stream_mesh``) and names the constructor otherwise."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.apps import ALL_APPS
+    from repro.core.scheduler import DualModeEngine, EngineConfig
+    from repro.core.sharded_stream import stream_mesh
+    mesh = stream_mesh((1,), ("dev",))
+    assert mesh.axis_types == (AxisType.Auto,)
+    app = ALL_APPS["gs"]
+    store = app.make_store()
+    DualModeEngine(app, store, EngineConfig(), mesh=mesh)
+    explicit = jax.make_mesh((1,), ("dev",),
+                             axis_types=(AxisType.Explicit,))
+    with pytest.raises(ValueError, match="stream_mesh"):
+        DualModeEngine(app, store, EngineConfig(), mesh=explicit)

@@ -161,6 +161,23 @@ def test_event_rate_limit(caplog):
     assert ev[0]["count"] == 5 and ev[0]["emitted"] == 1
 
 
+def test_device_peaks_resolve_reported_kinds():
+    from repro.runtime.telemetry import DEVICE_PEAKS, device_peaks
+    v5e = device_peaks("TPU v5 lite")
+    assert v5e == DEVICE_PEAKS["tpu v5 lite"]
+    assert (v5e["peak_flops"], v5e["hbm_bw"]) == (197e12, 819e9)
+    assert device_peaks("cpu") == DEVICE_PEAKS["cpu"]
+    assert device_peaks("TPU v5 lite", override=dict(
+        hbm_bw=1.0, link_bw=None))["hbm_bw"] == 1.0
+
+
+@pytest.mark.parametrize("kind", ["TPU v5e", "TPU v7x", "gpu", ""])
+def test_device_peaks_unknown_kind_raises(kind):
+    from repro.runtime.telemetry import device_peaks
+    with pytest.raises(KeyError):
+        device_peaks(kind)
+
+
 def test_registry_merge():
     a, b = Telemetry(), Telemetry()
     a.count("n", 2, kind="x")
