@@ -83,54 +83,6 @@ def device_kind() -> str:
         return "unknown"
 
 
-def cost_report() -> List[dict]:
-    """Per-chunk-shape HLO cost attribution + roofline annotation for the
-    smoke service cell (DESIGN.md §2.11): runs one telemetry-enabled
-    service pass with ``hlo_attribution`` on and reads the achieved
-    flops/bytes/bound fractions off the ``chunk.execute`` spans — the
-    same numbers the execute spans carry in a production trace."""
-    import json as _json
-    import tempfile
-
-    from repro.apps import ALL_APPS
-    from repro.core.intervals import ReplaySource, WatermarkPolicy
-    from repro.core.scheduler import DualModeEngine, EngineConfig
-    from repro.runtime.service import ServiceConfig, StreamService
-    from repro.runtime.telemetry import TelemetryConfig
-
-    app = ALL_APPS["gs"]
-    interval, n_iv, chunk = 64, 8, 4
-    src = ReplaySource(app.gen_events, interval * n_iv, seed=23,
-                       arrival_batch=interval, jitter=max(1, interval // 8))
-    store = app.make_store()
-    eng = DualModeEngine(app, store, EngineConfig(scheme="tstream"))
-    with tempfile.TemporaryDirectory() as tmp:
-        trace = os.path.join(tmp, "trace.json")
-        svc = StreamService(eng, ServiceConfig(
-            punct_interval=interval, chunk_intervals=chunk,
-            watermark=WatermarkPolicy(allowed_lateness=interval // 8),
-            telemetry=TelemetryConfig(trace_path=trace,
-                                      hlo_attribution=True)))
-        svc.run(src)
-        with open(trace) as f:
-            text = f.read().strip()
-        if not text.endswith("]"):
-            text += "]"
-        events = _json.loads(text)
-    rows = []
-    for ev in events:
-        a = ev.get("args", {})
-        if ev.get("name") == "chunk.execute" and "flops" in a:
-            rows.append(dict(
-                fig="perf_gate_cost", app="gs", scheme="tstream",
-                interval=interval, k=a.get("k"),
-                flops=a["flops"], bytes_written=a["bytes_written"],
-                gflops_s=a["gflops_s"], gbytes_s=a["gbytes_s"],
-                frac_compute=a["frac_compute"],
-                frac_memory=a["frac_memory"], bound=a["bound"]))
-    return rows
-
-
 def compare(base: dict, fresh_rows: List[dict], *, tolerance: float,
             abs_floor_s: float) -> Tuple[List[dict], bool]:
     """Per-row verdicts + whether the comparison is device-comparable."""
@@ -171,24 +123,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="record the fresh run as the new baseline")
     p.add_argument("--out", default=None,
                    help="write the verdict report JSON here")
-    p.add_argument("--cost", action="store_true",
-                   help="append per-chunk HLO flops/bytes cost attribution "
-                        "+ roofline annotation (telemetry execute spans)")
     args = p.parse_args(argv)
-
-    costs = []
-    if args.cost:
-        costs = cost_report()
-        print("perf-gate cost attribution (chunk.execute spans):")
-        for c in costs:
-            print(f"  k={c['k']}: {c['flops']:.2e} flops, "
-                  f"{c['bytes_written']:.2e} B written, "
-                  f"{c['gflops_s']:.2f} GF/s, {c['gbytes_s']:.2f} GB/s, "
-                  f"bound={c['bound']} "
-                  f"(compute {c['frac_compute']:.4f} / "
-                  f"memory {c['frac_memory']:.4f} of peak)")
-        if not costs:
-            print("  (no attributed execute spans — attribution failed?)")
 
     fresh = run_smoke()
     if args.update_baseline:
@@ -235,8 +170,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     print(f"perf-gate: {json.dumps(summary)}")
     if args.out:
         report = dict(summary=summary, verdicts=verdicts)
-        if args.cost:
-            report["cost_attribution"] = costs
         with open(args.out, "w") as f:
             json.dump(report, f, indent=2)
     if args.strict and comparable and n_reg:

@@ -61,11 +61,8 @@ ap.add_argument("--perfetto-out", default="",
                      "<path>.telemetry.json registry snapshot; the trace "
                      "is schema-validated after the run")
 ap.add_argument("--profile-dir", default="",
-                help="with --perfetto-out: jax.profiler per-chunk windows "
-                     "into this directory")
-ap.add_argument("--hlo-cost", action="store_true",
-                help="with --perfetto-out: annotate execute spans with "
-                     "compiled-HLO flops/bytes + roofline fractions")
+                help="with --perfetto-out: a jax.profiler trace of the run "
+                     "into this directory, each chunk's dispatch annotated")
 args = ap.parse_args()
 if args.devices:
     os.environ["XLA_FLAGS"] = " ".join(filter(None, [
@@ -141,8 +138,7 @@ def main():
         tcfg = None
         if args.perfetto_out:
             tcfg = TelemetryConfig(trace_path=args.perfetto_out,
-                                   profile_dir=args.profile_dir,
-                                   hlo_attribution=args.hlo_cost)
+                                   profile_dir=args.profile_dir)
         ref_cfg = ServiceConfig(
             punct_interval=iv, chunk_intervals=args.chunk,
             controller=controller, telemetry=tcfg,

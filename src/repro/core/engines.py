@@ -303,31 +303,35 @@ def tstream_scan_execute(values: jnp.ndarray, plan: ScanPlan,
     """
     sops, ch = plan.sops, plan.ch
     n = sops.uid.shape[0]
-    v0 = jnp.take(values, sops.uid, axis=0)                # [N, W]
-    pre = plan.af * v0 + plan.bf
-    post = plan.afi * v0 + plan.bfi
-    if plan.mx is not None:
-        mmask = plan.is_max_s[:, None]
-        pre = jnp.where(mmask, jnp.maximum(v0, plan.mx), pre)
-        post = jnp.where(mmask, jnp.maximum(v0, plan.mxi), post)
-    # every fun on this path is associative -> unconditionally successful;
-    # invalid ops were neutralized to identity, so their post == pre
-    success = sops.valid
+    with jax.named_scope("evaluate"):
+        v0 = jnp.take(values, sops.uid, axis=0)                # [N, W]
+        pre = plan.af * v0 + plan.bf
+        post = plan.afi * v0 + plan.bfi
+        if plan.mx is not None:
+            mmask = plan.is_max_s[:, None]
+            pre = jnp.where(mmask, jnp.maximum(v0, plan.mx), pre)
+            post = jnp.where(mmask, jnp.maximum(v0, plan.mxi), post)
+        # every fun on this path is associative -> unconditionally
+        # successful; invalid ops were neutralized to identity, so their
+        # post == pre
+        success = sops.valid
 
     # commit: last op of each chain defines the new state value.  The
     # update is a per-state gather + select, not an [N] scatter.
-    committed = jnp.take(post, plan.commit_pos, axis=0)         # [S+1, W]
-    new_values = jnp.where(plan.commit_ok[:, None], committed, values)
-    new_values = new_values.at[pad_uid].set(0.0)
+    with jax.named_scope("commit"):
+        committed = jnp.take(post, plan.commit_pos, axis=0)     # [S+1, W]
+        new_values = jnp.where(plan.commit_ok[:, None], committed, values)
+        new_values = new_values.at[pad_uid].set(0.0)
 
-    # invalid (padding) ops record nothing — match the oracle's layout
-    vmask = sops.valid
-    pre = jnp.where(vmask[:, None], pre, 0.0)
-    post = jnp.where(vmask[:, None], post, 0.0)
-    success = success & vmask
-    res = dict(pre=pre, post=post, success=success)
-    if not raw:
-        res = {k: ch.untake(v) for k, v in res.items()}
+    with jax.named_scope("evaluate"):
+        # invalid (padding) ops record nothing — match the oracle's layout
+        vmask = sops.valid
+        pre = jnp.where(vmask[:, None], pre, 0.0)
+        post = jnp.where(vmask[:, None], post, 0.0)
+        success = success & vmask
+        res = dict(pre=pre, post=post, success=success)
+        if not raw:
+            res = {k: ch.untake(v) for k, v in res.items()}
     stats = EngineStats(
         rounds=jnp.ceil(jnp.log2(ch.max_len.astype(jnp.float32) + 1)),
         n_chains=ch.n_chains, max_chain=ch.max_len,

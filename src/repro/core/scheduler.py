@@ -43,6 +43,15 @@ from .restructure import (megakernel_engaged, restructure, restructure_path,
 from .types import OpResults, StateStore
 
 
+def named(name: str, fn):
+    """``fn`` (a ``functools.partial``) under a stable name: ``jax.jit``
+    names its program ``jit_<name>``, which is how a profiler's
+    ``XLA Modules`` rows and a compiled module's ``HloModule`` line tell
+    the service's programs apart."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
     scheme: str = "tstream"
@@ -84,9 +93,11 @@ class DualModeEngine:
         self.app = app
         self.cfg = cfg
         self.init_store = store
-        self._step = jax.jit(partial(_step_impl, app=app, cfg=cfg))
+        self._step = jax.jit(named("tstream_step",
+                                   partial(_step_impl, app=app, cfg=cfg)))
         self._fused = jax.jit(
-            partial(_fused_impl, app=app, cfg=cfg, store=store),
+            named("tstream_chunk",
+                  partial(_fused_impl, app=app, cfg=cfg, store=store)),
             donate_argnums=0)
         # plan variants (adaptive control plane, DESIGN.md §2.9): extra
         # jitted builds of the SAME fused program with scheme/rung
@@ -94,7 +105,8 @@ class DualModeEngine:
         self._variants: Dict[Tuple[str, str], object] = {}
         # THE output program: all drivers post-process through this one
         # jitted function on identical shapes (see _post_stream)
-        self._post = jax.jit(partial(_post_stream, app=app))
+        self._post = jax.jit(named("tstream_outputs",
+                                   partial(_post_stream, app=app)))
         self._sharded = None
         if mesh is not None:
             from .sharded_stream import ShardedStream
@@ -193,8 +205,9 @@ class DualModeEngine:
             cfg = dataclasses.replace(self.cfg, scheme=sch,
                                       restructure_method=rung)
             self._variants[key] = jax.jit(
-                partial(_fused_impl, app=self.app, cfg=cfg,
-                        store=self.init_store),
+                named(f"tstream_chunk_{sch}_{rung}",
+                      partial(_fused_impl, app=self.app, cfg=cfg,
+                              store=self.init_store)),
                 donate_argnums=0)
         return key
 
@@ -244,48 +257,71 @@ class DualModeEngine:
             return ()
         n = interval * app.max_ops
         n_slots = store.values.shape[0]
-        has_max = any(store.table_is_max)
         radix = ("radix_partition",) if kernel_fits(n_slots, n) else ()
-        assoc = _assoc_fast(app, cfg)
-        if assoc and megakernel_engaged(
-                n, n_slots, method=cfg.restructure_method, has_max=has_max,
-                funs_simple=simple_affine_luts(app.funs) is not None,
-                use_pallas=True):
+        rung, path = self._chunk_route(interval, None)
+        if rung == "mega":
             return radix + (("fused_chain",)
                             if mega_kernel_fits(n, n_slots) else ())
-        path = restructure_path(n, store.pad_uid, rowmajor_ts=True,
-                                method=cfg.restructure_method)
-        kernels = radix if path == "partition" else ()
+        kernels = radix if rung == "partition" else ()
         # engines.evaluate's segmented-scan path (the fast path, or the
         # per-interval scan under abort repass)
-        if assoc or cfg.scheme == "tstream_scan" or (
-                cfg.scheme == "tstream" and app.associative_only
-                and not app.has_gates):
-            kernels += ("segscan_affine",) + (("segscan_max",)
-                                              if has_max else ())
+        if path == "segscan":
+            kernels += ("segscan_affine",) + (
+                ("segscan_max",) if any(store.table_is_max) else ())
         return kernels
 
     def post_outputs(self, res_all, ebs_all, n_intervals: int):
         """Materialize a chunk's per-interval outputs (blocks on D2H)."""
         return self._outs(res_all, ebs_all, n_intervals)
 
-    def chunk_lowered_text(self, values, batched, variant=None) -> str:
-        """Compiled (post-SPMD) HLO text for the chunk program that runs
-        these carry/batch shapes — the telemetry plane's opt-in cost
-        attribution hook (DESIGN.md §2.11).  Only shapes/dtypes are read
-        from ``values``/``batched``, never data, so it is safe to call
-        right before the donating dispatch.  This is a real AOT
-        lower+compile per shape (the jit call cache is separate), which
-        is why attribution defaults off."""
-        spec = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(jnp.shape(x), x.dtype),
-            (values, batched))
-        ts = jax.ShapeDtypeStruct((), jnp.int32)
+    def chunk_program(self, variant=None):
+        """The jitted chunk program :meth:`run_stream_chunk` dispatches."""
         if self._sharded is not None:
-            fn = self._sharded._impl
-        else:
-            fn = self._fused if variant is None else self._variants[variant]
-        return fn.lower(spec[0], spec[1], ts).compile().as_text()
+            return self._sharded._impl
+        return self._fused if variant is None else self._variants[variant]
+
+    def chunk_route(self, interval: int, variant=None) -> Tuple[str, str]:
+        """``(rung, path)`` of the chunk program for ``interval``-event
+        intervals: the restructure rung (``partition``, ``packed``,
+        ``lexsort``, ``mega``; ``none`` for a scheme without chains) and
+        the evaluation path (``segscan`` or ``lockstep``; the scheme's
+        name for a scheme without chains).  Mirrors ``_fused_impl``'s and
+        the sharded driver's dispatch: a host-side function of the
+        configuration and shapes, with nothing compiled or run."""
+        if self._sharded is not None:
+            return self._sharded.chunk_route(interval)
+        return self._chunk_route(interval, variant)
+
+    def _chunk_route(self, interval: int, variant) -> Tuple[str, str]:
+        cfg = self.cfg
+        if variant is not None:
+            cfg = dataclasses.replace(cfg, scheme=variant[0],
+                                      restructure_method=variant[1])
+        app, store = self.app, self.init_store
+        if cfg.scheme not in CHAIN_SCHEMES:
+            return "none", cfg.scheme
+        n = interval * app.max_ops
+        if _assoc_fast(app, cfg) and megakernel_engaged(
+                n, store.values.shape[0], method=cfg.restructure_method,
+                has_max=any(store.table_is_max),
+                funs_simple=simple_affine_luts(app.funs) is not None,
+                use_pallas=cfg.use_pallas):
+            return "mega", "segscan"
+        rung = restructure_path(n, store.pad_uid, rowmajor_ts=True,
+                                method=cfg.restructure_method)
+        return rung, _chain_path(app, cfg)
+
+    def chunk_lowered_text(self, values, batched, variant=None) -> str:
+        """Compiled (post-SPMD) HLO text of the chunk program that runs
+        these carry/batch shapes.  Only shapes, dtypes and shardings are
+        read from ``values``/``batched`` (``jax.ShapeDtypeStruct`` leaves
+        will do), never data.  Once the program has run at these shapes
+        the text comes from the executable the call compiled, with no
+        compilation of its own."""
+        spec = jax.tree_util.tree_map(shape_spec, (values, batched))
+        ts = jax.ShapeDtypeStruct((), jnp.int32)
+        return self.chunk_program(variant).lower(
+            spec[0], spec[1], ts).compile().as_text()
 
     # -- elastic resharding / carry API (DESIGN.md §2.10) -----------------
     # The service's chunk loop threads an OPAQUE carry: canonical [S+1, W]
@@ -332,6 +368,25 @@ class DualModeEngine:
         return self._sharded.reshard(carry, overrides)
 
 
+def shape_spec(x):
+    """``x``'s shape, dtype and (for a committed array) sharding."""
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return x
+    sharding = (x.sharding if isinstance(x, jax.Array)
+                and getattr(x, "committed", False) else None)
+    return jax.ShapeDtypeStruct(jnp.shape(x), x.dtype, sharding=sharding)
+
+
+def _chain_path(app: AppSpec, cfg: EngineConfig) -> str:
+    """The evaluation path of a chain scheme: ``segscan`` (associative
+    chains as segmented scans) or ``lockstep`` (chains walked op by op)."""
+    if _assoc_fast(app, cfg) or cfg.scheme == "tstream_scan" or (
+            cfg.scheme == "tstream" and app.associative_only
+            and not app.has_gates):
+        return "segscan"
+    return "lockstep"
+
+
 def _batches(stream: Dict[str, np.ndarray], interval: int):
     n = len(next(iter(stream.values())))
     for i in range(0, n - n % interval, interval):
@@ -349,14 +404,17 @@ def _eval_interval(store: StateStore, ops, *, app: AppSpec,
         # the segmented-scan path reads only 4 sorted columns — skip the rest
         light = (cfg.scheme in ("tstream", "tstream_scan")
                  and app.associative_only)
-        pres = restructure(ops, store.pad_uid, rowmajor_ts=True, light=light,
-                           method=cfg.restructure_method,
-                           use_pallas=cfg.use_pallas)
-    res, values, stats = evaluate(
-        store, ops, app.funs, cfg.scheme,
-        associative_only=app.associative_only, has_gates=app.has_gates,
-        n_partitions=cfg.n_partitions, max_dep_levels=cfg.max_dep_levels,
-        use_pallas=cfg.use_pallas, prestructured=pres)
+        with jax.named_scope("restructure"):
+            pres = restructure(ops, store.pad_uid, rowmajor_ts=True,
+                               light=light, method=cfg.restructure_method,
+                               use_pallas=cfg.use_pallas)
+    with jax.named_scope("evaluate"):
+        res, values, stats = evaluate(
+            store, ops, app.funs, cfg.scheme,
+            associative_only=app.associative_only, has_gates=app.has_gates,
+            n_partitions=cfg.n_partitions,
+            max_dep_levels=cfg.max_dep_levels,
+            use_pallas=cfg.use_pallas, prestructured=pres)
 
     batch = ops.n_ops // app.max_ops
     if cfg.abort_repass and app.may_abort:
@@ -365,22 +423,23 @@ def _eval_interval(store: StateStore, ops, *, app: AppSpec,
         # (Addresses the paper's §IV-F multi-write rollback limitation.)
         # Chain geometry only depends on uids, so the repass tightens the
         # ``valid`` mask in both layouts instead of re-sorting.
-        succ = res["success"].reshape(batch, app.max_ops)
-        valid = ops.valid.reshape(batch, app.max_ops)
-        txn_ok = jnp.all(succ | ~valid, axis=1)
-        keep = jnp.repeat(txn_ok, app.max_ops)
-        ops2 = dataclasses.replace(ops, valid=ops.valid & keep)
-        pres2 = None
-        if pres is not None:
-            sops, ch = pres
-            pres2 = (dataclasses.replace(sops,
-                                         valid=sops.valid & ch.take(keep)),
-                     ch)
-        res, values, stats = evaluate(
-            store, ops2, app.funs, cfg.scheme,
-            associative_only=app.associative_only, has_gates=app.has_gates,
-            n_partitions=cfg.n_partitions, max_dep_levels=cfg.max_dep_levels,
-            use_pallas=cfg.use_pallas, prestructured=pres2)
+        with jax.named_scope("abort_repass"):
+            succ = res["success"].reshape(batch, app.max_ops)
+            valid = ops.valid.reshape(batch, app.max_ops)
+            txn_ok = jnp.all(succ | ~valid, axis=1)
+            keep = jnp.repeat(txn_ok, app.max_ops)
+            ops2 = dataclasses.replace(ops, valid=ops.valid & keep)
+            pres2 = None
+            if pres is not None:
+                sops, ch = pres
+                pres2 = (dataclasses.replace(
+                    sops, valid=sops.valid & ch.take(keep)), ch)
+            res, values, stats = evaluate(
+                store, ops2, app.funs, cfg.scheme,
+                associative_only=app.associative_only,
+                has_gates=app.has_gates, n_partitions=cfg.n_partitions,
+                max_dep_levels=cfg.max_dep_levels,
+                use_pallas=cfg.use_pallas, prestructured=pres2)
 
     return res, values, stats
 
@@ -421,7 +480,8 @@ def _post_interval(res, ebs, *, app: AppSpec):
 def _step_impl(store: StateStore, events, ts_base, *, app: AppSpec,
                cfg: EngineConfig):
     # -- compute mode: pre-process + postpone state access (D1) ------------
-    ops, ebs = build_opbatch(app, store, events, ts_base)
+    with jax.named_scope("register"):
+        ops, ebs = build_opbatch(app, store, events, ts_base)
     # -- state access mode: dynamic restructuring execution (D2) -----------
     res, values, stats = _eval_interval(store, ops, app=app, cfg=cfg)
     return res, ebs, values, stats
@@ -450,9 +510,11 @@ def _fused_impl(values, events_b, ts0, *, app: AppSpec, cfg: EngineConfig,
     store = dataclasses.replace(store, values=values)
 
     # compute mode for ALL intervals at once (interval-parallel)
-    ts_bases = ts0 + jnp.arange(n_intervals, dtype=jnp.int32) * interval
-    ops_all, ebs_all = jax.vmap(
-        lambda ev, tb: build_opbatch(app, store, ev, tb))(events_b, ts_bases)
+    with jax.named_scope("register"):
+        ts_bases = ts0 + jnp.arange(n_intervals, dtype=jnp.int32) * interval
+        ops_all, ebs_all = jax.vmap(
+            lambda ev, tb: build_opbatch(app, store, ev, tb))(events_b,
+                                                              ts_bases)
 
     assoc_fast = _assoc_fast(app, cfg)
 
@@ -481,10 +543,11 @@ def _fused_impl(values, events_b, ts0, *, app: AppSpec, cfg: EngineConfig,
     # body evaluates one interval from its prestructured batch
     pres_all = None
     if cfg.scheme in CHAIN_SCHEMES:
-        pres_all = restructure_stream(
-            ops_all, store.pad_uid, rowmajor_ts=True,
-            method=cfg.restructure_method, use_pallas=cfg.use_pallas,
-            block_rows=cfg.block_param("radix_partition"))
+        with jax.named_scope("restructure"):
+            pres_all = restructure_stream(
+                ops_all, store.pad_uid, rowmajor_ts=True,
+                method=cfg.restructure_method, use_pallas=cfg.use_pallas,
+                block_rows=cfg.block_param("radix_partition"))
 
     def body(values, xs):
         ops, pres = xs
@@ -517,17 +580,22 @@ def _fused_assoc(store: StateStore, ops_all, *, app: AppSpec,
                           use_pallas=cfg.use_pallas):
         return _fused_assoc_mega(store, ops_all, luts=luts, cfg=cfg)
 
-    pres_all = restructure_stream(
-        ops_all, store.pad_uid, rowmajor_ts=True, light=True,
-        method=cfg.restructure_method, use_pallas=cfg.use_pallas,
-        block_rows=cfg.block_param("radix_partition"))
-    plan_all = jax.vmap(
-        lambda o, p: tstream_scan_plan(store, o, app.funs,
-                                       prestructured=p))(ops_all, pres_all)
-    plan_all = tstream_scan_coefs_stream(plan_all, use_pallas=cfg.use_pallas,
-                                         block_rows=cfg.block_param("segscan"))
+    with jax.named_scope("restructure"):
+        pres_all = restructure_stream(
+            ops_all, store.pad_uid, rowmajor_ts=True, light=True,
+            method=cfg.restructure_method, use_pallas=cfg.use_pallas,
+            block_rows=cfg.block_param("radix_partition"))
+    with jax.named_scope("evaluate"):
+        plan_all = jax.vmap(
+            lambda o, p: tstream_scan_plan(store, o, app.funs,
+                                           prestructured=p))(ops_all,
+                                                             pres_all)
+        plan_all = tstream_scan_coefs_stream(
+            plan_all, use_pallas=cfg.use_pallas,
+            block_rows=cfg.block_param("segscan"))
 
     def body(values, plan):
+        # tstream_scan_execute names its own evaluate and commit parts
         res, new_values, stats = tstream_scan_execute(
             values, plan, store.pad_uid)
         return new_values, (res, stats)
@@ -549,17 +617,20 @@ def _fused_assoc_mega(store: StateStore, ops_all, *, luts,
     from repro.kernels.megakernel import fused_chain_eval
 
     a_lut, b_lut = luts
-    sops_all, ch_all = restructure_stream(
-        ops_all, store.pad_uid, rowmajor_ts=True, light=True,
-        method="partition", use_pallas=cfg.use_pallas, geometry=False,
-        block_rows=cfg.block_param("radix_partition"))
+    with jax.named_scope("restructure"):
+        sops_all, ch_all = restructure_stream(
+            ops_all, store.pad_uid, rowmajor_ts=True, light=True,
+            method="partition", use_pallas=cfg.use_pallas, geometry=False,
+            block_rows=cfg.block_param("radix_partition"))
 
     def body(values, xs):
         sops, ch = xs
-        res, new_values, stats = fused_chain_eval(
-            values, sops, ch, store.pad_uid, a_lut=a_lut, b_lut=b_lut,
-            use_pallas=cfg.use_pallas)
-        res = {k: ch.untake(v) for k, v in res.items()}
+        # one fused partition -> segscan -> commit dispatch: all evaluate
+        with jax.named_scope("evaluate"):
+            res, new_values, stats = fused_chain_eval(
+                values, sops, ch, store.pad_uid, a_lut=a_lut, b_lut=b_lut,
+                use_pallas=cfg.use_pallas)
+            res = {k: ch.untake(v) for k, v in res.items()}
         return new_values, (res, stats)
 
     values, (res_all, stats) = jax.lax.scan(body, store.values,

@@ -67,7 +67,9 @@ from .ownership import (LAYOUTS, bucket_by_owner, build_ownership,
                         exchange_capacity, make_local_store, migration_plan,
                         permute_values, route_gather, unchunk_output,
                         unpermute_values, unroute_gather)
-from .restructure import Chains, megakernel_engaged, restructure_stream
+from .restructure import (Chains, megakernel_engaged, restructure_path,
+                          restructure_stream)
+from .scheduler import named
 from .types import OpBatch, StateStore
 
 log = logging.getLogger(__name__)
@@ -149,7 +151,8 @@ class ShardedStream:
         # identical function + identical [n_intervals, N, ...] shapes =>
         # identical compilation => bit-identical outputs
         from .scheduler import _post_stream
-        self._post = jax.jit(partial(_post_stream, app=app))
+        self._post = jax.jit(named("tstream_outputs",
+                                   partial(_post_stream, app=app)))
         self.last_stats: Optional[Dict] = None
 
     def _bind_ownership(self, overrides) -> None:
@@ -166,11 +169,12 @@ class ShardedStream:
                 owner = fwd // self.own.per
             self.probe = build_probe_route(self.store.n_slots, owner,
                                            miss_owner=self.n_route)
-        self._impl = jax.jit(partial(_sharded_blocks_impl, eng=self),
-                             donate_argnums=0)
-        self._to_blocks = jax.jit(partial(_to_blocks_impl, eng=self))
+        self._impl = jax.jit(_chunk_program(self), donate_argnums=0)
+        self._to_blocks = jax.jit(named("tstream_carry_in",
+                                        partial(_to_blocks_impl, eng=self)))
         # NO donation: snapshots read the carry mid-run and keep using it
-        self._from_blocks = jax.jit(partial(_from_blocks_impl, eng=self))
+        self._from_blocks = jax.jit(named(
+            "tstream_carry_out", partial(_from_blocks_impl, eng=self)))
 
     @property
     def owners(self):
@@ -209,7 +213,8 @@ class ShardedStream:
             return blocks, 0
         new_own = build_ownership(self.store, self._n_owners, overrides)
         dst, nidx, cap = migration_plan(self.own, new_own)
-        fn = jax.jit(partial(_migrate_impl, eng=self, cap=cap),
+        fn = jax.jit(named("tstream_migrate",
+                           partial(_migrate_impl, eng=self, cap=cap)),
                      donate_argnums=0)
         blocks, moved = fn(blocks, jnp.asarray(dst), jnp.asarray(nidx))
         self._bind_ownership(overrides)
@@ -286,8 +291,7 @@ class ShardedStream:
         the escalation; results for shipped ops are unaffected, only the
         padding widens)."""
         self.exchange_slack = float(slack)
-        self._impl = jax.jit(partial(_sharded_blocks_impl, eng=self),
-                             donate_argnums=0)
+        self._impl = jax.jit(_chunk_program(self), donate_argnums=0)
 
     def run_chunk(self, blocks, batched, ts0: int):
         """Chunked service entry (see ``DualModeEngine.run_stream_chunk``).
@@ -302,10 +306,34 @@ class ShardedStream:
         """
         return self._impl(blocks, batched, jnp.int32(ts0))
 
+    def chunk_route(self, interval: int):
+        """``(rung, path)`` of the chunk program at the bound exchange
+        slack (see ``DualModeEngine.chunk_route``): each device restructures
+        the ``n_dev × cap`` rows it receives over its local block."""
+        n_loc = (int(interval) // self.n_dev) * self.app.max_ops
+        rows = self.n_dev * exchange_capacity(n_loc, self.n_route,
+                                              self.exchange_slack)
+        lpad = (self.own.s_pad if self.layout == "shared_everything"
+                else self.own.per)
+        if self.assoc and megakernel_engaged(
+                rows, lpad + 1, method=self.cfg.restructure_method,
+                has_max=any(self.store.table_is_max),
+                funs_simple=simple_affine_luts(self.app.funs) is not None,
+                use_pallas=self.cfg.use_pallas):
+            return "mega", "segscan"
+        rung = restructure_path(rows, lpad, rowmajor_ts=True,
+                                method=self.cfg.restructure_method)
+        return rung, ("segscan" if self.assoc else "lockstep")
+
 
 # ---------------------------------------------------------------------------
 # the jitted whole-stream program (block-carry form)
 # ---------------------------------------------------------------------------
+def _chunk_program(eng: ShardedStream):
+    return named("tstream_chunk_sharded",
+                 partial(_sharded_blocks_impl, eng=eng))
+
+
 def _lane_width(eng: ShardedStream) -> int:
     """Pallas fast path: lane-pad state once per stream (operands pad
     after the exchange so wire bytes stay at W lanes)."""
@@ -513,75 +541,79 @@ def _stream_body(blocks, sim_b, events_loc, *, eng: ShardedStream, dims,
         core = jax.lax.axis_index(axes[1])
 
     # ---- compute mode: event-parallel op registration (all intervals) ---
-    ts_bases = (ts0 + jnp.arange(n_intervals, dtype=jnp.int32) * interval
-                + dev * E_loc)
-    ops_all, ebs_all = jax.vmap(
-        lambda ev, tb: build_opbatch(app, eng.store, ev, tb))(
-            events_loc, ts_bases)
-    base = dev * N_loc
-    gflat = jnp.broadcast_to(base + jnp.arange(N_loc, dtype=jnp.int32),
-                             (n_intervals, N_loc))
-    gate_all = jnp.where(ops_all.gate >= 0, ops_all.gate + base, -1)
+    with jax.named_scope("register"):
+        ts_bases = (ts0 + jnp.arange(n_intervals, dtype=jnp.int32) * interval
+                    + dev * E_loc)
+        ops_all, ebs_all = jax.vmap(
+            lambda ev, tb: build_opbatch(app, eng.store, ev, tb))(
+                events_loc, ts_bases)
+        base = dev * N_loc
+        gflat = jnp.broadcast_to(base + jnp.arange(N_loc, dtype=jnp.int32),
+                                 (n_intervals, N_loc))
+        gate_all = jnp.where(ops_all.gate >= 0, ops_all.gate + base, -1)
 
     # ---- owner routing (values-independent, hoisted) --------------------
-    uid_perm = jnp.take(own.fwd, ops_all.uid)            # [n_i, N_loc]
-    if eng.probe is not None:
-        dst_v = eng.probe.owners_of(
-            ops_all.uid.reshape(-1)).reshape(n_intervals, N_loc)
-    elif layout == "shared_everything":
-        dst_v = uid_perm % n_dev
-    else:
-        dst_v = uid_perm // per
-    dst = jnp.where(ops_all.valid, dst_v, n_route).astype(jnp.int32)
-    plans = jax.vmap(lambda d: bucket_by_owner(d, n_route, cap))(dst)
+    with jax.named_scope("exchange"):
+        uid_perm = jnp.take(own.fwd, ops_all.uid)            # [n_i, N_loc]
+        if eng.probe is not None:
+            dst_v = eng.probe.owners_of(
+                ops_all.uid.reshape(-1)).reshape(n_intervals, N_loc)
+        elif layout == "shared_everything":
+            dst_v = uid_perm % n_dev
+        else:
+            dst_v = uid_perm // per
+        dst = jnp.where(ops_all.valid, dst_v, n_route).astype(jnp.int32)
+        plans = jax.vmap(lambda d: bucket_by_owner(d, n_route, cap))(dst)
 
-    if layout == "shared_everything":
-        uid_send = jnp.where(ops_all.valid, uid_perm, lpad)
-    else:
-        uid_send = jnp.where(ops_all.valid,
-                             uid_perm - jnp.minimum(dst_v, n_route - 1) * per,
-                             lpad)
-    rg = jax.vmap(route_gather, in_axes=(0, 0, None))
-    send = dict(
-        uid=rg(plans, uid_send, lpad),
-        fun=rg(plans, ops_all.fun, 0),
-        operand=rg(plans, ops_all.operand, 0.0),
-        valid=rg(plans, ops_all.valid, False),
-        ts=rg(plans, ops_all.ts, 0),
-        slot=rg(plans, ops_all.slot, 0),
-    )
-    if not eng.assoc:
-        send["gate"] = rg(plans, gate_all, -1)
-        send["gflat"] = rg(plans, gflat, N_glob)
+        if layout == "shared_everything":
+            uid_send = jnp.where(ops_all.valid, uid_perm, lpad)
+        else:
+            uid_send = jnp.where(
+                ops_all.valid,
+                uid_perm - jnp.minimum(dst_v, n_route - 1) * per, lpad)
+        rg = jax.vmap(route_gather, in_axes=(0, 0, None))
+        send = dict(
+            uid=rg(plans, uid_send, lpad),
+            fun=rg(plans, ops_all.fun, 0),
+            operand=rg(plans, ops_all.operand, 0.0),
+            valid=rg(plans, ops_all.valid, False),
+            ts=rg(plans, ops_all.ts, 0),
+            slot=rg(plans, ops_all.slot, 0),
+        )
+        if not eng.assoc:
+            send["gate"] = rg(plans, gate_all, -1)
+            send["gflat"] = rg(plans, gflat, N_glob)
 
-    # ---- THE exchange: one all_to_all for the whole stream --------------
-    recv = {k: jax.lax.all_to_all(v, eng.route_axes, split_axis=1,
-                                  concat_axis=1)
-            for k, v in send.items()}
-    if layout == "shared_per_socket":
-        # intra-socket: every core sees the socket's full routed set, in
-        # flat source-device order (socket-major) so rows stay ts-sorted
-        recv = {k: jax.lax.all_gather(v, axes[1], axis=1)
+        # ---- THE exchange: one all_to_all for the whole stream ----------
+        recv = {k: jax.lax.all_to_all(v, eng.route_axes, split_axis=1,
+                                      concat_axis=1)
+                for k, v in send.items()}
+        if layout == "shared_per_socket":
+            # intra-socket: every core sees the socket's full routed set,
+            # in flat source-device order (socket-major) so rows stay
+            # ts-sorted
+            recv = {k: jax.lax.all_gather(v, axes[1], axis=1)
+                    for k, v in recv.items()}
+            recv = {k: jnp.moveaxis(v, 1, 2) for k, v in recv.items()}
+        R = n_dev * dims["cap"]
+        recv = {k: v.reshape((n_intervals, R) + v.shape[4 if layout ==
+                             "shared_per_socket" else 3:])
                 for k, v in recv.items()}
-        recv = {k: jnp.moveaxis(v, 1, 2) for k, v in recv.items()}
-    R = n_dev * dims["cap"]
-    recv = {k: v.reshape((n_intervals, R) + v.shape[4 if layout ==
-                         "shared_per_socket" else 3:])
-            for k, v in recv.items()}
 
-    rvalid = recv["valid"]
-    ruid = recv["uid"]
-    if layout == "shared_per_socket":
-        rvalid = rvalid & ((ruid % eng.n_core) == core)
-    operand = recv["operand"]
-    if Wp > W:
-        operand = jnp.pad(operand, ((0, 0), (0, 0), (0, Wp - W)))
-    zeros_i = jnp.zeros((n_intervals, R), jnp.int32)
-    rops = OpBatch(
-        uid=ruid, ts=recv["ts"], txn=zeros_i, slot=recv["slot"],
-        kind=zeros_i, fun=recv["fun"],
-        gate=recv.get("gate", jnp.full((n_intervals, R), -1, jnp.int32)),
-        operand=operand, valid=rvalid)
+        rvalid = recv["valid"]
+        ruid = recv["uid"]
+        if layout == "shared_per_socket":
+            rvalid = rvalid & ((ruid % eng.n_core) == core)
+        operand = recv["operand"]
+        if Wp > W:
+            operand = jnp.pad(operand, ((0, 0), (0, 0), (0, Wp - W)))
+        zeros_i = jnp.zeros((n_intervals, R), jnp.int32)
+        rops = OpBatch(
+            uid=ruid, ts=recv["ts"], txn=zeros_i, slot=recv["slot"],
+            kind=zeros_i, fun=recv["fun"],
+            gate=recv.get("gate",
+                          jnp.full((n_intervals, R), -1, jnp.int32)),
+            operand=operand, valid=rvalid)
 
     # ---- local state / store ------------------------------------------
     if layout == "shared_everything":
@@ -617,61 +649,72 @@ def _stream_body(blocks, sim_b, events_loc, *, eng: ShardedStream, dims,
             # kernels/megakernel).  The ownership merge is unchanged.
             from repro.kernels.megakernel import fused_chain_eval
             a_lut, b_lut = mega_luts
-            sops_all, ch_all = restructure_stream(
-                rops, lpad, rowmajor_ts=True, light=True,
-                method="partition", use_pallas=cfg.use_pallas,
-                geometry=False,
-                block_rows=cfg.block_param("radix_partition"))
+            with jax.named_scope("restructure"):
+                sops_all, ch_all = restructure_stream(
+                    rops, lpad, rowmajor_ts=True, light=True,
+                    method="partition", use_pallas=cfg.use_pallas,
+                    geometry=False,
+                    block_rows=cfg.block_param("radix_partition"))
 
             def sbody(vals, xs):
                 sops, ch = xs
-                res, new_vals, _ = fused_chain_eval(
-                    vals, sops, ch, lpad, a_lut=a_lut, b_lut=b_lut,
-                    use_pallas=cfg.use_pallas)
+                with jax.named_scope("evaluate"):
+                    res, new_vals, _ = fused_chain_eval(
+                        vals, sops, ch, lpad, a_lut=a_lut, b_lut=b_lut,
+                        use_pallas=cfg.use_pallas)
                 if own_mask is not None:
-                    new_vals = jax.lax.pmax(
-                        jnp.where(own_mask[:, None], new_vals, -jnp.inf),
-                        merge_axes)
-                    new_vals = new_vals.at[lpad].set(0.0)
+                    with jax.named_scope("commit"):
+                        new_vals = jax.lax.pmax(
+                            jnp.where(own_mask[:, None], new_vals,
+                                      -jnp.inf), merge_axes)
+                        new_vals = new_vals.at[lpad].set(0.0)
                 return new_vals, res
 
             vals_fin, res_sorted = jax.lax.scan(sbody, vals0,
                                                 (sops_all, ch_all))
-            res_routed = {k: jax.vmap(Chains.untake)(ch_all, v)
-                          for k, v in res_sorted.items()}
+            with jax.named_scope("evaluate"):
+                res_routed = {k: jax.vmap(Chains.untake)(ch_all, v)
+                              for k, v in res_sorted.items()}
         else:
-            pres_all = restructure_stream(
-                rops, lpad, rowmajor_ts=True, light=True,
-                method=cfg.restructure_method, use_pallas=cfg.use_pallas,
-                block_rows=cfg.block_param("radix_partition"))
-            plan_all = jax.vmap(
-                lambda o, p: tstream_scan_plan(lstore, o, app.funs,
-                                               prestructured=p))(rops,
-                                                                 pres_all)
-            plan_all = tstream_scan_coefs_stream(
-                plan_all, use_pallas=cfg.use_pallas,
-                block_rows=cfg.block_param("segscan"))
+            with jax.named_scope("restructure"):
+                pres_all = restructure_stream(
+                    rops, lpad, rowmajor_ts=True, light=True,
+                    method=cfg.restructure_method,
+                    use_pallas=cfg.use_pallas,
+                    block_rows=cfg.block_param("radix_partition"))
+            with jax.named_scope("evaluate"):
+                plan_all = jax.vmap(
+                    lambda o, p: tstream_scan_plan(
+                        lstore, o, app.funs, prestructured=p))(rops,
+                                                               pres_all)
+                plan_all = tstream_scan_coefs_stream(
+                    plan_all, use_pallas=cfg.use_pallas,
+                    block_rows=cfg.block_param("segscan"))
 
             def sbody(vals, plan):
+                # tstream_scan_execute names its evaluate and commit parts
                 res, new_vals, _ = tstream_scan_execute(vals, plan, lpad,
                                                         raw=True)
                 if own_mask is not None:
                     # ownership-masked SELECT (one writer per slot) —
                     # exact, unlike delta summation
-                    new_vals = jax.lax.pmax(
-                        jnp.where(own_mask[:, None], new_vals, -jnp.inf),
-                        merge_axes)
-                    new_vals = new_vals.at[lpad].set(0.0)
+                    with jax.named_scope("commit"):
+                        new_vals = jax.lax.pmax(
+                            jnp.where(own_mask[:, None], new_vals,
+                                      -jnp.inf), merge_axes)
+                        new_vals = new_vals.at[lpad].set(0.0)
                 return new_vals, res
 
             vals_fin, res_sorted = jax.lax.scan(sbody, vals0, plan_all)
-            res_routed = {k: jax.vmap(Chains.untake)(plan_all.ch, v)
-                          for k, v in res_sorted.items()}
+            with jax.named_scope("evaluate"):
+                res_routed = {k: jax.vmap(Chains.untake)(plan_all.ch, v)
+                              for k, v in res_sorted.items()}
     else:
-        pres_all = restructure_stream(
-            rops, lpad, rowmajor_ts=True,
-            method=cfg.restructure_method, use_pallas=cfg.use_pallas,
-            block_rows=cfg.block_param("radix_partition"))
+        with jax.named_scope("restructure"):
+            pres_all = restructure_stream(
+                rops, lpad, rowmajor_ts=True,
+                method=cfg.restructure_method, use_pallas=cfg.use_pallas,
+                block_rows=cfg.block_param("radix_partition"))
         lk = partial(
             _lockstep_interval, eng=eng, R=R, N_glob=N_glob,
             pad_uid=lpad, Wq=Wp, axis=axes[0], per=per, s_pad=s_pad,
@@ -679,36 +722,38 @@ def _stream_body(blocks, sim_b, events_loc, *, eng: ShardedStream, dims,
 
         def sbody(vals, xs):
             (sops, ch), gfr = xs
-            vals2, res = lk(vals, sops, ch, gfr, dev=dev)
+            with jax.named_scope("evaluate"):
+                vals2, res = lk(vals, sops, ch, gfr, dev=dev)
             return vals2, res
 
         vals_fin, res_routed = jax.lax.scan(
             sbody, vals0, (pres_all, recv["gflat"]))
 
     # ---- reverse exchange: results home to their source device ----------
-    if layout == "shared_per_socket":
-        # socket-complete results (each op evaluated on exactly one core)
-        pp = {k: (jax.lax.psum(v.astype(jnp.int32), axes[1]) > 0
-                  if v.dtype == jnp.bool_ else jax.lax.psum(v, axes[1]))
-              for k, v in res_routed.items()}
-        back = {k: v.reshape((n_intervals, eng.n_sockets, eng.n_core, cap)
-                             + v.shape[2:])
-                for k, v in pp.items()}
-        back = {k: jax.lax.dynamic_index_in_dim(v, core, axis=2,
-                                                keepdims=False)
+    with jax.named_scope("exchange"):
+        if layout == "shared_per_socket":
+            # socket-complete results (each op evaluated on exactly one core)
+            pp = {k: (jax.lax.psum(v.astype(jnp.int32), axes[1]) > 0
+                      if v.dtype == jnp.bool_ else jax.lax.psum(v, axes[1]))
+                  for k, v in res_routed.items()}
+            back = {k: v.reshape((n_intervals, eng.n_sockets, eng.n_core, cap)
+                                 + v.shape[2:])
+                    for k, v in pp.items()}
+            back = {k: jax.lax.dynamic_index_in_dim(v, core, axis=2,
+                                                    keepdims=False)
+                    for k, v in back.items()}
+        else:
+            back = {k: v.reshape((n_intervals, n_dev, cap) + v.shape[2:])
+                    for k, v in res_routed.items()}
+        back = {k: jax.lax.all_to_all(v, eng.route_axes, split_axis=1,
+                                      concat_axis=1)
                 for k, v in back.items()}
-    else:
-        back = {k: v.reshape((n_intervals, n_dev, cap) + v.shape[2:])
-                for k, v in res_routed.items()}
-    back = {k: jax.lax.all_to_all(v, eng.route_axes, split_axis=1,
-                                  concat_axis=1)
+        back = {k: v.reshape((n_intervals, n_route * cap) + v.shape[3:])
+                for k, v in back.items()}
+        res_loc = {
+            k: jax.vmap(lambda p, v: unroute_gather(p, v, n_route, cap))(
+                plans, v)
             for k, v in back.items()}
-    back = {k: v.reshape((n_intervals, n_route * cap) + v.shape[3:])
-            for k, v in back.items()}
-    res_loc = {
-        k: jax.vmap(lambda p, v: unroute_gather(p, v, n_route, cap))(
-            plans, v)
-        for k, v in back.items()}
 
     # per-device exchange stats; reduced outside the shard_map ([1, n_i]
     # rows concatenate to [n_dev, n_i] under the fully-specified spec)

@@ -109,9 +109,16 @@ Unified telemetry plane (DESIGN.md §2.11, ``runtime/telemetry.py``):
 * With ``ServiceConfig.telemetry.trace_path`` set, every pipeline stage
   (source pull → assembly → admission → dispatch → execute → commit →
   ``controller.decide`` → snapshot publish → ``reshard.apply``) emits a
-  Chrome-trace/Perfetto span; ``profile_dir`` adds per-chunk
-  ``jax.profiler`` windows and ``hlo_attribution`` attaches compiled-HLO
-  flops/bytes + roofline fractions to execute spans.
+  Chrome-trace/Perfetto span, and so do the executor's waits: for work
+  while a chunk is uncommitted (``executor.hold``) or with nothing in
+  flight (``executor.idle``), and for the device and the D2H copy inside
+  ``chunk.commit`` (``chunk.fetch``).  Each dispatch opens a profiler
+  annotation, and at the end of the run the trace gets each chunk
+  program's op → phase table (``op_phases``).  ``profile_dir`` runs a
+  ``jax.profiler`` trace over the run.
+* Every run counts its compilations (``jax.compiles``) and its chunks by
+  restructure rung and evaluation path (``chunks``); each chunk record
+  carries its ``rung`` and ``path``.
 * Replay-safety contract: telemetry never feeds ``decide()`` — a
   tracing-enabled run is bitwise identical to a tracing-off run,
   including crash → restore → replay (tests/test_telemetry.py).  The
@@ -138,13 +145,14 @@ from repro.ckpt import (checkpoint_steps, load_checkpoint, prune_checkpoints,
                         read_manifest_meta, save_checkpoint,
                         verify_checkpoint)
 from repro.core.intervals import IntervalAssembler, WatermarkPolicy
+from repro.core.scheduler import shape_spec
 
 from .controller import (AdvisoryTiming, ControllerConfig, Plan,
                          PlanController, replay_plan)
 from .faults import FaultPlane, TransientSourceError
-from .telemetry import (ChunkProfiler, CostAttributor, Telemetry,
-                        TelemetryConfig, empty_stats, make_tracer,
-                        stats_view)
+from .telemetry import (Profiler, Telemetry, TelemetryConfig,
+                        compile_watch, empty_stats, make_tracer,
+                        op_phase_table, stats_view)
 
 log = logging.getLogger(__name__)
 
@@ -362,21 +370,16 @@ class StreamService:
         rec = ServiceRun()
         self.last_run = rec
         # -- observability plane (DESIGN.md §2.11) ---------------------
-        # The registry is always on (no clocks of its own); the tracer,
-        # profiler and cost attributor are opt-in and provably off the
-        # replay path: with all three enabled the run stays bitwise
-        # identical to a bare one.
+        # The registry is always on (no clocks of its own); the tracer
+        # and profiler are opt-in and provably off the replay path: with
+        # both enabled the run stays bitwise identical to a bare one.
+        # (the tracer and the compile count start with the executor)
         tcfg = cfg.telemetry
         tele = Telemetry(record_cap=tcfg.record_cap if tcfg else 4096)
         rec.telemetry = tele
-        tracer = make_tracer(tcfg, tele)
-        profiler = ChunkProfiler(tcfg.profile_dir if tcfg else "")
-        cost_attr = None
-        if tcfg is not None and tcfg.hlo_attribution:
-            cost_attr = CostAttributor(
-                n_devices=(eng._sharded.n_dev
-                           if eng._sharded is not None else 1))
-        costs: Dict = {}     # shape key -> analyze_hlo dict (or None)
+        # traced runs: each chunk program's (jit, argument specs) by shape,
+        # for its op -> phase table once the run is over
+        programs: Dict = {}
         init = eng.init_store.values if values is None else values
         src = iter(source)
         state = dict(exhausted=False, to_skip=int(skip_intervals), err=None)
@@ -504,22 +507,26 @@ class StreamService:
 
         def commit_oldest(check_crash: bool = True):
             (g0, kk, res, ebs, infos, xst, item_plan, qfill,
-             t_disp, cost) = in_flight.popleft()
+             t_disp, route) = in_flight.popleft()
             commit_span = tracer.span("chunk.commit", g0=g0, k=kk)
             commit_span.__enter__()
-            outs = eng.post_outputs(res, ebs, kk)
+            st_d = xst or {}
+            # the blocking part: wait for the device, copy to the host
+            with tracer.span("chunk.fetch", g0=g0, k=kk):
+                outs = eng.post_outputs(res, ebs, kk)
+                suc, est, xs = jax.device_get(
+                    (res["success"], st_d.get("engine"),
+                     st_d.get("exchange")))
             t_commit = time.perf_counter()
             rec.t_last_commit = t_commit
             now = time.monotonic()
-            # the device-execute span: the dispatch->commit wall window,
-            # reconstructed from stamps the accounting already takes (no
-            # extra clock reads on the replay path); cost attribution
-            # and roofline fractions ride on its args
+            # the dispatch->commit wall window, from stamps the accounting
+            # already takes (no extra clock reads on the replay path): it
+            # holds the device's work, the wait for the next dispatch and
+            # the fetch
             if tracer.enabled:
-                xargs = dict(g0=g0, k=kk)
-                if cost is not None and cost_attr is not None:
-                    xargs.update(cost_attr.annotate(cost, now - t_disp))
-                tracer.complete_at("chunk.execute", t_disp, now, **xargs)
+                tracer.complete_at("chunk.execute", t_disp, now, g0=g0,
+                                   k=kk)
             if progress["last_commit"] is not None:
                 progress["lat"].append(now - progress["last_commit"])
             progress["last_commit"] = now
@@ -530,21 +537,19 @@ class StreamService:
                 lat_s=float(now - t_disp), qfill=int(qfill),
                 scheme=(item_plan.scheme if item_plan is not None
                         else eng.cfg.scheme),
+                rung=route[0], path=route[1],
                 fail=0, ops=0, max_chain=0, n_chains=0, rounds=0,
                 x_drop=0, x_ship=0, x_fill=0, x_cap=0)
-            suc = np.asarray(jax.device_get(res["success"]))
+            tele.count("chunks", rung=route[0], path=route[1])
+            suc = np.asarray(suc)
             entry["ops"] = int(suc.size)
             entry["fail"] = int(suc.size - np.sum(suc))
-            st_d = xst or {}
-            est = st_d.get("engine")
             if est is not None:
-                es = jax.device_get(est)
-                entry["max_chain"] = int(np.max(es.max_chain))
-                entry["n_chains"] = int(np.min(es.n_chains))
-                entry["rounds"] = int(np.max(es.rounds))
-            xs = st_d.get("exchange")
+                entry["max_chain"] = int(np.max(est.max_chain))
+                entry["n_chains"] = int(np.min(est.n_chains))
+                entry["rounds"] = int(np.max(est.rounds))
             if xs is not None:
-                st = jax.device_get(xs)
+                st = xs
                 dropped_now = int(np.sum(st["dropped"]))
                 rec.exchange_dropped += dropped_now
                 rec.exchange_shipped += int(np.sum(st["shipped"]))
@@ -634,7 +639,7 @@ class StreamService:
             rec.snapshots.append(step)
             snap_span.__exit__(None, None, None)
 
-        seen_shapes = set()     # (variant-key, chunk size) already compiled
+        routes: Dict = {}       # shape -> (rung, path); compiled shapes
 
         def dispatch(batched, kk: int, infos, plan, qfill):
             nonlocal vals, g_next
@@ -697,31 +702,32 @@ class StreamService:
                 applied["plan"] = plan
             shape = (variant, None if plan is None else plan.slack,
                      None if plan is None else plan.owners, kk)
-            if shape not in seen_shapes:
+            route = routes.get(shape)
+            if route is None:
                 # first dispatch of this (variant, slack, K) compiles a
                 # new program: drop the warm-chunk latency window so the
                 # watchdog judges it against ``watchdog_grace_s``, not
                 # the warm median — same reason grace covers chunk 0
-                seen_shapes.add(shape)
+                route = routes[shape] = eng.chunk_route(interval, variant)
                 progress["lat"].clear()
-            if cost_attr is not None and shape not in costs:
-                # opt-in per-chunk-shape attribution: shapes/dtypes are
-                # read BEFORE the donating call; the AOT compile is the
-                # documented one-time cost per shape (DESIGN.md §2.11)
-                costs[shape] = cost_attr.chunk_cost(
-                    eng, vals, batched, variant=variant)
+                if tracer.enabled:
+                    # argument specs are read BEFORE the donating call
+                    programs[shape] = (
+                        eng.chunk_program(variant), variant,
+                        jax.tree_util.tree_map(shape_spec, (vals, batched)))
             vals_ok["safe"] = False     # the carry is being donated
             t_disp = time.monotonic()
-            with tracer.span("chunk.dispatch", g0=g_next, k=kk):
-                with profiler.chunk(g_next):
-                    res, ebs, new_vals, xst = eng.run_stream_chunk(
-                        vals, batched, ts_base_for(g_next, interval),
-                        variant=variant)
+            with tracer.span("chunk.dispatch", g0=g_next, k=kk,
+                             rung=route[0], path=route[1]), \
+                    tracer.annotation("chunk", g_next):
+                res, ebs, new_vals, xst = eng.run_stream_chunk(
+                    vals, batched, ts_base_for(g_next, interval),
+                    variant=variant)
             vals = new_vals
             vals_ok["safe"] = True
             progress["t"] = time.monotonic()
             in_flight.append((g_next, kk, res, ebs, infos, xst, plan,
-                              qfill, t_disp, costs.get(shape)))
+                              qfill, t_disp, route))
             g_next += kk
             if faults is not None:
                 faults.on_executor_chunk()
@@ -746,12 +752,25 @@ class StreamService:
             execution, so the main thread assembles and stages chunk i+1
             while chunk i computes.  The loop re-checks ``state['err']``
             between items so a watchdog verdict stops it promptly."""
-            try:
+            def next_item():
                 while state["err"] is None:
                     try:
-                        item = work_q.get(timeout=0.05)
+                        return work_q.get(timeout=0.05)
                     except queue.Empty:
                         continue
+                return None
+
+            try:
+                while True:
+                    # the wait for work: a dispatched chunk's result is
+                    # held until its successor's dispatch commits it
+                    if in_flight:
+                        wait = tracer.span("executor.hold",
+                                           g0=in_flight[-1][0])
+                    else:
+                        wait = tracer.span("executor.idle")
+                    with wait:
+                        item = next_item()
                     if item is None:
                         break
                     progress["busy"] = True
@@ -803,6 +822,9 @@ class StreamService:
                         faults.abort()  # wake any injected stall/hang
                     return
 
+        tracer = make_tracer(tcfg, tele)
+        profiler = Profiler(tcfg.profile_dir if tcfg else "")
+        compiles = compile_watch(tele, tracer).__enter__()
         worker = threading.Thread(target=executor, daemon=True,
                                   name="stream-service-executor")
         worker.start()
@@ -955,15 +977,46 @@ class StreamService:
                          source=srcst, error=err, plane=faults,
                          chunks=list(hist), controller=ctl,
                          hung_thread=hung_thread, advisory=advisory)
-            tracer.close()
+            self._close_telemetry(tracer, compiles, programs)
             raise err
 
         rec.final_values = np.asarray(jax.device_get(eng.carry_out(vals)))
         self._finish(rec, asm, ready, crashed=False, stranded=stranded,
                      source=srcst, plane=faults, chunks=list(hist),
                      controller=ctl, advisory=advisory)
-        tracer.close()
+        self._close_telemetry(tracer, compiles, programs)
         return rec
+
+    def _close_telemetry(self, tracer, compiles, programs: Dict) -> None:
+        """End the run's compile count, publish each chunk program's op ->
+        phase table into the trace (``op_phases``), close the trace.
+
+        The table is read from the program's compiled HLO text
+        (:meth:`DualModeEngine.chunk_lowered_text`), which comes from the
+        executable the run's dispatches compiled: nothing compiles here.
+        A program the engine has since rebound (a new exchange slack or
+        ownership) is skipped: its text would need a new compilation."""
+        compiles.__exit__(None, None, None)
+        if not tracer.enabled:
+            return
+        eng, done = self.engine, set()
+        for fn, variant, (vals, batched) in programs.values():
+            if fn is not eng.chunk_program(variant):
+                continue
+            try:
+                table = op_phase_table(
+                    eng.chunk_lowered_text(vals, batched, variant=variant))
+            except Exception as e:
+                log.warning("op_phases not written (%s: %s)",
+                            type(e).__name__, e)
+                continue
+            key = (table["program"], tuple(sorted(table["phases"].items())))
+            if key not in done:
+                done.add(key)
+                tracer.metadata("op_phases", name=table["program"],
+                                phases=table["phases"],
+                                containers=table["containers"])
+        tracer.close()
 
     def _make_controller(self, controller_state: Optional[Dict]
                          ) -> Optional[PlanController]:

@@ -21,9 +21,13 @@ One registry for every observability surface the runtime grew piecemeal:
 * **Span tracing** — Chrome-trace / Perfetto-compatible JSONL covering
   the whole service pipeline (source pull → interval assembly →
   admission → chunk dispatch → device execute → commit →
-  ``controller.decide`` → snapshot publish → ``reshard.apply``), plus
-  opt-in per-chunk cost attribution (compiled-HLO flops/bytes via
-  ``launch/hlo_analysis.py``, achieved-vs-peak roofline fractions).
+  ``controller.decide`` → snapshot publish → ``reshard.apply``), the
+  executor's waits (``executor.hold``/``executor.idle``, ``chunk.fetch``)
+  and compilations (``jax.compile``), on one clock whose epoch the file
+  carries; buffered and written off the span's thread.
+* **Device work by phase** — :func:`op_phase_table` maps each op of a
+  compiled chunk program to the named scope it runs under, so a device
+  trace's ops can be summed by phase.
 
 **Replay-safety contract** (the §2.11 hard invariant): telemetry is
 observability only.  The tracer reads a clock *only when a trace sink is
@@ -40,9 +44,11 @@ snapshot so the old surface survives as a compatibility view.
 """
 from __future__ import annotations
 
+import collections
 import json
 import logging
 import os
+import re
 import threading
 import time
 from dataclasses import dataclass
@@ -478,10 +484,10 @@ PIPELINE_STAGES = ("source.pull", "admission", "assembly", "chunk.submit",
 
 
 class TraceWriter:
-    """Incremental Chrome-trace JSON array writer.  Events stream out
-    one-per-line so a crashed run leaves a readable prefix (the format's
-    closing ``]`` is optional for trace viewers and for
-    :func:`validate_trace`); :meth:`close` makes the file strict JSON."""
+    """Incremental Chrome-trace JSON array writer.  Events go out one per
+    line, so a crashed run leaves a readable prefix (the format's closing
+    ``]`` is optional for trace viewers and for :func:`validate_trace`);
+    :meth:`close` makes the file strict JSON."""
 
     def __init__(self, path: str):
         d = os.path.dirname(path)
@@ -492,18 +498,22 @@ class TraceWriter:
         self._f.write("[")
         self._first = True
         self._lock = threading.Lock()
-        self._n = 0
 
     def emit(self, ev: Dict) -> None:
-        line = json.dumps(ev, separators=(",", ":"), default=_json_default)
+        self.write_lines([json.dumps(ev, separators=(",", ":"),
+                                     default=_json_default)])
+
+    def write_lines(self, lines: Sequence[str]) -> None:
+        """Append already-serialized events in one write, then flush."""
+        if not lines:
+            return
         with self._lock:
             if self._f.closed:
                 return
-            self._f.write(("\n" if self._first else ",\n") + line)
+            head = "\n" if self._first else ",\n"
+            self._f.write(head + ",\n".join(lines))
             self._first = False
-            self._n += 1
-            if self._n % 32 == 0:
-                self._f.flush()
+            self._f.flush()
 
     def flush(self) -> None:
         with self._lock:
@@ -519,7 +529,8 @@ class TraceWriter:
 
 class _Span:
     """A ``ph="X"`` complete event; ``set(**args)`` attaches arguments
-    any time before exit (cost attribution lands this way)."""
+    any time before exit.  Exit appends one tuple to the tracer's buffer:
+    no serialization and no registry update on the span's thread."""
 
     __slots__ = ("_tr", "name", "cat", "args", "_t0")
 
@@ -527,7 +538,7 @@ class _Span:
         self._tr = tracer
         self.name = name
         self.cat = cat
-        self.args = dict(args)
+        self.args = args
 
     def set(self, **kw) -> "_Span":
         self.args.update(kw)
@@ -538,8 +549,8 @@ class _Span:
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tr._emit_complete(self.name, self.cat, self._t0,
-                                time.monotonic_ns(), self.args)
+        self._tr._append(self.name, self.cat, self._t0, time.monotonic_ns(),
+                         self.args)
         return False
 
 
@@ -568,10 +579,16 @@ class NullTracer:
     def span(self, name, cat="pipeline", **args):
         return _NULL_SPAN
 
+    def annotation(self, name, step):
+        return _NULL_SPAN
+
     def complete_at(self, name, t0_s, t1_s, cat="pipeline", **args):
         pass
 
     def instant(self, name, cat="pipeline", **args):
+        pass
+
+    def metadata(self, event, **args):
         pass
 
     def close(self):
@@ -580,70 +597,175 @@ class NullTracer:
 
 NULL_TRACER = NullTracer()
 
+# the buffer's marks for events that are not complete spans
+_INSTANT, _META = -1, -2
+
 
 class Tracer:
-    """Span emitter over a :class:`TraceWriter`.  Timestamps come from
-    ``time.monotonic_ns`` anchored at construction; span durations also
-    land in the registry as ``span.<name>`` histograms (observability
-    only — nothing on the decision path reads them)."""
+    """Span emitter over a :class:`TraceWriter`, on one clock.
+
+    Every stamp is ``CLOCK_MONOTONIC`` nanoseconds (``time.monotonic_ns``;
+    ``time.monotonic`` seconds for :meth:`complete_at`).  The file's first
+    event is the metadata event ``trace_epoch``, whose ``args.epoch_ns``
+    is the clock's reading at ``ts`` 0: an event's host time is
+    ``epoch_ns + ts * 1e3`` nanoseconds, with nothing fitted.
+
+    A span's exit appends one tuple (name, category, start, end, thread,
+    args) to a buffer.  A writer thread drains the buffer every
+    ``flush_s`` seconds: it serializes the events, writes and flushes them
+    in one call, and adds the drained spans' durations to the registry's
+    ``span.<name>`` histograms in bulk (observability only — nothing on
+    the decision path reads them).  A run that dies leaves every event
+    drained before it; :meth:`close` drains the rest and closes the file.
+    """
 
     enabled = True
 
     def __init__(self, writer: TraceWriter, registry: Optional[Telemetry]
-                 = None, process_name: str = "repro-stream-service"):
+                 = None, process_name: str = "repro-stream-service",
+                 flush_s: float = 0.25):
         self._w = writer
         self._reg = registry
         self.pid = os.getpid()
         self.epoch_ns = time.monotonic_ns()
-        self._tids: Dict[int, int] = {}
-        self._tlock = threading.Lock()
+        self._buf: collections.deque = collections.deque()
+        self._names: Dict[int, str] = {}    # thread ident -> thread name
+        self._tids: Dict[int, int] = {}     # thread ident -> trace tid
+        self._drain_lock = threading.Lock()
+        self._warned = False
+        self._heads: Dict[Tuple, str] = {}   # (name, cat, thread) -> head
+        self._args_seen: Dict[Tuple, str] = {}
+        self._dumps = json.JSONEncoder(separators=(",", ":"),
+                                       default=_json_default).encode
+        self._w.emit(dict(name="trace_epoch", ph="M", ts=0, pid=self.pid,
+                          tid=0, args=dict(name="CLOCK_MONOTONIC",
+                                           epoch_ns=self.epoch_ns)))
         self._w.emit(dict(name="process_name", ph="M", ts=0, pid=self.pid,
                           tid=0, args=dict(name=process_name)))
+        self._stop = threading.Event()
+        self._flush_s = float(flush_s)
+        self._writer = threading.Thread(target=self._run, daemon=True,
+                                        name="trace-writer")
+        self._writer.start()
 
-    def _tid(self) -> int:
+    # -- the span's thread: one tuple, no serialization -------------------
+    def _append(self, name, cat, t0_ns, t1_ns, args) -> None:
         ident = threading.get_ident()
-        with self._tlock:
-            tid = self._tids.get(ident)
-            if tid is None:
-                tid = self._tids[ident] = len(self._tids) + 1
-                self._w.emit(dict(
-                    name="thread_name", ph="M", ts=0, pid=self.pid, tid=tid,
-                    args=dict(name=threading.current_thread().name)))
-        return tid
+        if ident not in self._names:
+            self._names[ident] = threading.current_thread().name
+        self._buf.append((name, cat, t0_ns, t1_ns, ident, args))
 
     def span(self, name: str, cat: str = "pipeline", **args) -> _Span:
         return _Span(self, name, cat, args)
 
-    def _emit_complete(self, name, cat, t0_ns, t1_ns, args) -> None:
-        ev = dict(name=name, cat=cat, ph="X",
-                  ts=(t0_ns - self.epoch_ns) / 1e3,
-                  dur=max((t1_ns - t0_ns) / 1e3, 0.0),
-                  pid=self.pid, tid=self._tid())
-        if args:
-            ev["args"] = args
-        self._w.emit(ev)
-        if self._reg is not None:
-            self._reg.observe("span." + name, (t1_ns - t0_ns) / 1e9)
+    def annotation(self, name: str, step: int):
+        """A ``jax.profiler.TraceAnnotation`` named ``<name> <step>``: the
+        host plane of any running profiler shows it, whoever started the
+        profiler; without one it costs a check."""
+        import jax
+        return jax.profiler.TraceAnnotation(f"{name} {int(step)}")
 
     def complete_at(self, name: str, t0_s: float, t1_s: float,
                     cat: str = "pipeline", **args) -> None:
-        """Emit a complete event from two ``time.monotonic()`` stamps the
-        caller already took for its own accounting — the execute span is
-        reconstructed this way so tracing adds no clock read of its own
-        to the dispatch/commit path."""
-        t0_ns = int(t0_s * 1e9)
-        t1_ns = int(t1_s * 1e9)
-        self._emit_complete(name, cat, t0_ns, t1_ns, args)
+        """A complete event from two ``time.monotonic()`` stamps the
+        caller already took for its own accounting (no clock read of the
+        tracer's own)."""
+        self._append(name, cat, int(t0_s * 1e9), int(t1_s * 1e9), args)
 
     def instant(self, name: str, cat: str = "pipeline", **args) -> None:
-        ev = dict(name=name, cat=cat, ph="i", s="t",
-                  ts=(time.monotonic_ns() - self.epoch_ns) / 1e3,
-                  pid=self.pid, tid=self._tid())
-        if args:
-            ev["args"] = args
-        self._w.emit(ev)
+        self._append(name, cat, time.monotonic_ns(), _INSTANT, args)
+
+    def metadata(self, event: str, **args) -> None:
+        """A ``ph="M"`` event called ``event`` at ``ts`` 0 (``args`` must
+        hold ``name``)."""
+        self._append(event, "", self.epoch_ns, _META, args)
+
+    # -- the writer thread ------------------------------------------------
+    def _run(self) -> None:
+        while not self._stop.wait(self._flush_s):
+            self.flush()
+
+    def _head(self, name: str, cat: str, ident: int,
+              lines: List[str]) -> str:
+        """An event's fixed leading fields, up to its ``ts`` value; the
+        first event of a thread also writes the thread's name."""
+        tid = self._tids.get(ident)
+        if tid is None:
+            tid = self._tids[ident] = len(self._tids) + 1
+            lines.append(json.dumps(dict(
+                name="thread_name", ph="M", ts=0, pid=self.pid, tid=tid,
+                args=dict(name=self._names.get(ident, str(ident)))),
+                separators=(",", ":")))
+        return '{"name":%s,"cat":%s,"pid":%d,"tid":%d,"ts":' % (
+            json.dumps(name), json.dumps(cat), self.pid, tid)
+
+    def flush(self) -> None:
+        """Drain the buffer into the file and the registry now."""
+        with self._drain_lock:
+            n = len(self._buf)
+            if not n:
+                return
+            pop, epoch, heads = self._buf.popleft, self.epoch_ns, self._heads
+            lines: List[str] = []
+            durs: Dict[str, List[int]] = {}
+            for _ in range(n):
+                name, cat, t0, t1, ident, args = pop()
+                if t1 == _META:
+                    line = '{"name":%s,"ph":"M","ts":0,"pid":%d,"tid":0' % (
+                        json.dumps(name), self.pid)
+                else:
+                    key = (name, cat, ident)
+                    head = heads.get(key)
+                    if head is None:
+                        head = heads[key] = self._head(name, cat, ident,
+                                                       lines)
+                    if t1 == _INSTANT:
+                        line = '%s%.3f,"ph":"i","s":"t"' % (
+                            head, (t0 - epoch) / 1e3)
+                    else:
+                        d = max(t1 - t0, 0)
+                        line = '%s%.3f,"ph":"X","dur":%.3f' % (
+                            head, (t0 - epoch) / 1e3, d / 1e3)
+                        ds = durs.get(name)
+                        if ds is None:
+                            ds = durs[name] = []
+                        ds.append(d)
+                if args:
+                    try:
+                        line += ',"args":' + self._args_json(args)
+                    except (TypeError, ValueError) as e:
+                        if not self._warned:
+                            self._warned = True
+                            log.warning("trace event %r written without "
+                                        "its args (%s)", name, e)
+                lines.append(line + "}")
+            self._w.write_lines(lines)
+            if self._reg is not None:
+                for name, d in durs.items():
+                    self._reg.observe_many("span." + name,
+                                           np.asarray(d) / 1e9)
+
+    def _args_json(self, args: Dict) -> str:
+        """``args`` as JSON, remembered by value and type (``1``, ``1.0``
+        and ``True`` are equal keys): most spans repeat a few small
+        argument sets (an admission's outcome and queue fill)."""
+        try:
+            key = (tuple(args.items()), tuple(map(type, args.values())))
+            text = self._args_seen.get(key)
+        except TypeError:               # an unhashable value
+            return self._dumps(args)
+        if text is None:
+            if len(self._args_seen) >= 4096:
+                self._args_seen.clear()
+            text = self._args_seen[key] = self._dumps(args)
+        return text
 
     def close(self) -> None:
+        self._stop.set()
+        if self._writer.is_alive() and \
+                self._writer is not threading.current_thread():
+            self._writer.join()
+        self.flush()
         self._w.close()
 
 
@@ -653,8 +775,7 @@ class TelemetryConfig:
     defaults off; any combination is replay-safe (DESIGN.md §2.11)."""
 
     trace_path: str = ""        # Perfetto/Chrome JSONL sink; "" = no tracing
-    profile_dir: str = ""       # jax.profiler per-chunk windows; "" = off
-    hlo_attribution: bool = False  # compiled-HLO cost per chunk shape
+    profile_dir: str = ""       # jax.profiler window over the run; "" = off
     record_cap: int = 4096      # bound on every structured record log
 
 
@@ -668,11 +789,11 @@ def make_tracer(tcfg: Optional[TelemetryConfig],
 # ---------------------------------------------------------------------------
 # profiling hooks (opt-in; never on the replay path)
 # ---------------------------------------------------------------------------
-class ChunkProfiler:
-    """Per-chunk ``jax.profiler`` windows: one ``StepTraceAnnotation``
-    per dispatched chunk inside a run-scoped ``start_trace`` window.
-    Fully inert unless ``profile_dir`` is set; failures degrade to a
-    one-time warning, never to a run error."""
+class Profiler:
+    """A ``jax.profiler`` trace over one run, into ``profile_dir``.  Fully
+    inert unless ``profile_dir`` is set; failures degrade to a one-time
+    warning, never to a run error.  Chunks show in it by the tracer's
+    per-dispatch annotation (:meth:`Tracer.annotation`)."""
 
     def __init__(self, profile_dir: str = ""):
         self.profile_dir = profile_dir
@@ -688,13 +809,6 @@ class ChunkProfiler:
         except Exception as e:
             log.warning("jax.profiler start failed (%s: %s) — profiling "
                         "disabled for this run", type(e).__name__, e)
-
-    def chunk(self, step: int):
-        if not self.active:
-            return _NULL_SPAN
-        import jax
-        return jax.profiler.StepTraceAnnotation("service_chunk",
-                                                step_num=int(step))
 
     def stop(self) -> None:
         if not self.active:
@@ -746,57 +860,172 @@ def device_peaks(device_kind: Optional[str] = None,
     return row
 
 
-class CostAttributor:
-    """Opt-in per-chunk cost attribution: lower+compile the chunk program
-    for the observed shapes once per (variant, slack, owners, K) shape
-    key, run ``launch/hlo_analysis.analyze_hlo`` over the compiled HLO,
-    and annotate execute spans with achieved-vs-peak roofline fractions.
-    The AOT lowering is a real compile — documented one-time cost per
-    shape, which is why this is opt-in (``hlo_attribution=True``)."""
+# ---------------------------------------------------------------------------
+# compilations (one jax.monitoring listener per process)
+# ---------------------------------------------------------------------------
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_WATCHERS: List[Tuple[Telemetry, Any]] = []
+_WATCH_LOCK = threading.Lock()
+_LISTENING = False
 
-    def __init__(self, n_devices: int = 1):
-        self.n_devices = max(int(n_devices), 1)
-        self._peaks: Optional[Dict[str, float]] = None
-        self._warned = False
 
-    def chunk_cost(self, engine, values, batched,
-                   variant=None) -> Optional[Dict]:
-        """Trip-weighted flops/bytes/wire for the chunk program that runs
-        these shapes (None on any failure — attribution never breaks a
-        run)."""
-        try:
-            from repro.launch.hlo_analysis import analyze_hlo
-            hlo = engine.chunk_lowered_text(values, batched, variant=variant)
-            return analyze_hlo(hlo, self.n_devices)
-        except Exception as e:
-            if not self._warned:
-                self._warned = True
-                log.warning("per-chunk HLO cost attribution failed "
-                            "(%s: %s) — execute spans will carry no cost "
-                            "args", type(e).__name__, e)
-            return None
+def _on_compile(event: str, secs: float, **_) -> None:
+    if event != COMPILE_EVENT:
+        return
+    with _WATCH_LOCK:
+        watchers = list(_WATCHERS)
+    if not watchers:
+        return
+    now = time.monotonic()
+    for reg, tracer in watchers:
+        reg.count("jax.compiles")
+        tracer.complete_at("jax.compile", now - secs, now, cat="jax",
+                           seconds=secs)
 
-    def peaks(self) -> Dict[str, float]:
-        if self._peaks is None:
-            self._peaks = device_peaks()
-        return self._peaks
 
-    def annotate(self, cost: Dict, dur_s: float) -> Dict:
-        """Achieved-vs-peak annotation for one executed chunk window."""
-        pk = self.peaks()
-        dur = max(float(dur_s), 1e-12)
-        flops = float(cost.get("dot_flops", 0.0))
-        byts = float(cost.get("bytes_written", 0.0))
-        wire = float(cost.get("wire_bytes_per_device", 0.0))
-        fracs = dict(
-            frac_compute=flops / dur / pk["peak_flops"],
-            frac_memory=byts / dur / pk["hbm_bw"],
-            frac_link=wire / dur / pk["link_bw"])
-        bound = max(fracs, key=fracs.get)
-        return dict(
-            flops=flops, bytes_written=byts, wire_bytes_per_device=wire,
-            gflops_s=flops / dur / 1e9, gbytes_s=byts / dur / 1e9,
-            bound=bound.replace("frac_", ""), **fracs)
+class compile_watch:
+    """Count every XLA compilation while the block runs into ``registry``
+    as ``jax.compiles``, and put a ``jax.compile`` span on the compiling
+    thread of ``tracer``.  The listener is registered with
+    ``jax.monitoring`` once per process; a compilation counts in every
+    watch open at the time."""
+
+    def __init__(self, registry: Telemetry, tracer=NULL_TRACER):
+        self._entry = (registry, tracer)
+
+    def __enter__(self) -> "compile_watch":
+        global _LISTENING
+        with _WATCH_LOCK:
+            if not _LISTENING:
+                import jax
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_compile)
+                _LISTENING = True
+            _WATCHERS.append(self._entry)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        with _WATCH_LOCK:
+            _WATCHERS.remove(self._entry)
+        return False
+
+
+# ---------------------------------------------------------------------------
+# device work by phase: HLO instruction -> named scope
+# ---------------------------------------------------------------------------
+# the chunk program's named scopes (core/scheduler.py,
+# core/sharded_stream.py, core/engines.py); an op takes the outermost one
+# on its op_name path
+PHASES = ("register", "restructure", "evaluate", "abort_repass", "commit",
+          "exchange")
+CONTAINER_OPS = ("while", "conditional", "call")
+
+_COMP_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(")
+_INSTR_RE = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_OPNAME_RE = re.compile(r'op_name="([^"]*)"')
+_CALLEE_RE = re.compile(
+    r"\b(body|condition|to_apply|true_computation|false_computation|calls)="
+    r"%?([\w.\-]+)")
+_BRANCHES_RE = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+
+
+def _opcode(rhs: str) -> Tuple[str, List[str]]:
+    """The opcode of an instruction's right-hand side (after its shape)
+    and the names of its operands."""
+    i = 0
+    if rhs.startswith("("):             # tuple shape: skip to its close
+        depth = 0
+        for i, ch in enumerate(rhs):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        i += 1
+    else:
+        i = rhs.find(" ")
+    rest = rhs[i:].lstrip()
+    k = rest.find("(")
+    if k < 0:
+        return rest, []
+    depth, j = 0, k
+    for j in range(k, len(rest)):
+        depth += (rest[j] == "(") - (rest[j] == ")")
+        if depth == 0:
+            break
+    return rest[:k], _OPERAND_RE.findall(rest[k:j])
+
+
+def op_phase_table(hlo_text: str, phases: Sequence[str] = PHASES) -> Dict:
+    """The device ops of a compiled program, each with its phase.
+
+    Reads the program's compiled HLO text: the ops a device runs are the
+    instructions of the entry computation and, through each ``while``,
+    ``conditional`` and ``call``, of the computations those run (never
+    the inside of a fusion).  An op's phase is the first of ``phases`` on
+    its ``op_name`` path — scan and while bodies sit between the program's
+    name and the phase.  An op that names none takes, if a fusion, its
+    fused root's phase or else the first its fused instructions name;
+    failing that, the phase of its first operand that has one (the
+    compiler drops the metadata of some ops, such as a cumulative sum's
+    ``reduce-window``); failing that ``"other"``.  Returns
+    ``dict(program=<module name>, phases={op: phase}, containers=[ops
+    whose time is their bodies'])``."""
+    comps: Dict[str, List[Tuple]] = {}
+    entry = cur = None
+    program = ""
+    for line in hlo_text.splitlines():
+        if line.startswith("HloModule "):
+            program = line.split()[1].rstrip(",")
+            continue
+        m = _COMP_RE.match(line)
+        if m and line.rstrip().endswith("{"):
+            cur = m.group(2)
+            comps[cur] = []
+            if m.group(1):
+                entry = cur
+            continue
+        m = _INSTR_RE.match(line)
+        if m and cur is not None:
+            rhs = m.group(3)
+            op, operands = _opcode(rhs)
+            comps[cur].append((m.group(2), op, operands, rhs,
+                               bool(m.group(1))))
+
+    def phase_of(rhs: str) -> str:
+        m = _OPNAME_RE.search(rhs)
+        path = m.group(1).split("/") if m else ()
+        return next((p for p in path if p in phases), "other")
+
+    def fused_phase(comp: str) -> str:
+        instrs = sorted(comps.get(comp, ()), key=lambda i: not i[4])
+        return next((p for p in (phase_of(i[3]) for i in instrs)
+                     if p != "other"), "other")
+
+    table: Dict[str, str] = {}
+    known: Dict[str, str] = {}          # every instruction's phase so far
+    containers: List[str] = []
+    todo, seen = [entry], set()
+    while todo:
+        comp = todo.pop()
+        if comp is None or comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for name, op, operands, rhs, _ in comps[comp]:
+            callees = [c for _, c in _CALLEE_RE.findall(rhs)]
+            if op in CONTAINER_OPS:
+                containers.append(name)
+                todo += callees
+                for grp in _BRANCHES_RE.findall(rhs):
+                    todo += [c.strip().lstrip("%") for c in grp.split(",")]
+                continue
+            phase = phase_of(rhs)
+            if phase == "other" and op == "fusion" and callees:
+                phase = fused_phase(callees[0])
+            if phase == "other":
+                phase = next((known[o] for o in operands
+                              if known.get(o, "other") != "other"), "other")
+            table[name] = known[name] = phase
+    return dict(program=program, phases=table, containers=sorted(containers))
 
 
 # ---------------------------------------------------------------------------
@@ -806,21 +1035,28 @@ _VALID_PH = {"X", "i", "I", "C", "M", "B", "E"}
 
 
 def _parse_trace(path: str) -> List[Dict]:
+    """The events of a trace file.  A file without its closing ``]`` is a
+    crashed run's prefix: a last line cut short by the crash is dropped;
+    any other malformed line is an error."""
     with open(path) as f:
         raw = f.read()
     body = raw.strip()
     if body.startswith("["):
         body = body[1:]
-    if body.rstrip().endswith("]"):
+    closed = body.rstrip().endswith("]")
+    if closed:
         body = body.rstrip()[:-1]
+    lines = body.splitlines()
     events = []
-    for i, line in enumerate(body.splitlines(), 1):
+    for i, line in enumerate(lines, 1):
         line = line.strip().rstrip(",")
         if not line:
             continue
         try:
             events.append(json.loads(line))
         except ValueError as e:
+            if not closed and i == len(lines):
+                break
             raise ValueError(f"{path}:{i}: invalid trace event JSON: {e}")
     return events
 
