@@ -123,13 +123,15 @@ class EngineStats:
     rounds: jnp.ndarray          # sequential depth of the schedule
     n_chains: jnp.ndarray        # parallel width available
     max_chain: jnp.ndarray       # longest chain
+    fallback_ops: jnp.ndarray    # ops the cycle fallback applied (else 0)
     n_ops: int                   # total decomposed ops (incl. padding)
     scheme: str = ""
     path: str = ""               # "segscan" | "lockstep" | ...
 
 
 jax.tree_util.register_dataclass(
-    EngineStats, data_fields=["rounds", "n_chains", "max_chain"],
+    EngineStats, data_fields=["rounds", "n_chains", "max_chain",
+                              "fallback_ops"],
     meta_fields=["n_ops", "scheme", "path"])
 
 
@@ -335,7 +337,8 @@ def tstream_scan_execute(values: jnp.ndarray, plan: ScanPlan,
     stats = EngineStats(
         rounds=jnp.ceil(jnp.log2(ch.max_len.astype(jnp.float32) + 1)),
         n_chains=ch.n_chains, max_chain=ch.max_len,
-        n_ops=n, scheme="tstream", path="segscan")
+        fallback_ops=jnp.int32(0), n_ops=n, scheme="tstream",
+        path="segscan")
     return res, new_values, stats
 
 
@@ -430,7 +433,7 @@ def eval_tstream_lockstep(store: StateStore, ops: OpBatch,
             values, sops, ch, funs, jnp.ones((n,), bool), results, n,
             store.pad_uid, ch.max_len)
         rounds = ch.max_len
-        unresolved_ops = jnp.zeros((n,), bool)
+        fallback_ops = jnp.int32(0)
     else:
         lvl, unresolved = _chain_levels(sops, ch, n, max_dep_levels)
         rounds = jnp.int32(0)
@@ -446,32 +449,47 @@ def eval_tstream_lockstep(store: StateStore, ops: OpBatch,
         # sequential fallback for ops in unresolved chains (cycles)
         unresolved_ops_sorted = jnp.take(unresolved, ch.seg_id) & sops.valid
         unresolved_ops = ch.untake(unresolved_ops_sorted)
-        values, results = _sequential_sweep(values, ops, funs, results,
-                                            mask_flat=unresolved_ops,
-                                            pad_uid=store.pad_uid)
-        rounds = rounds + jnp.sum(unresolved_ops)
+        values, results, fallback_ops = _sequential_sweep(
+            values, ops, funs, results, mask_flat=unresolved_ops,
+            pad_uid=store.pad_uid)
+        rounds = rounds + fallback_ops
 
     res = {k: v[:n] for k, v in results.items()}
     stats = EngineStats(rounds=rounds, n_chains=ch.n_chains,
-                        max_chain=ch.max_len, n_ops=n,
-                        scheme="tstream", path="lockstep")
+                        max_chain=ch.max_len, fallback_ops=fallback_ops,
+                        n_ops=n, scheme="tstream", path="lockstep")
     return res, values, stats
 
 
 # ---------------------------------------------------------------------------
 # Sequential oracle / LOCK schedule
 # ---------------------------------------------------------------------------
+def sweep_selected(step, carry, order, selected):
+    """``carry = step(j, carry)`` for each ``j = order[k]`` with
+    ``selected[j]``, in ``order``'s order.
+
+    The trip count is the number of selected ops, not ``len(order)``: a
+    sweep over a batch's few dependency-cycle ops runs a few steps.
+    Returns ``(carry, count)``.
+    """
+    sel = jnp.take(selected, order)
+    count = jnp.sum(sel, dtype=jnp.int32)
+    (pos,) = jnp.nonzero(sel, size=order.shape[0], fill_value=0)
+    picked = jnp.take(order, pos)
+    carry = jax.lax.fori_loop(0, count,
+                              lambda k, c: step(picked[k], c), carry)
+    return carry, count
+
+
 def _sequential_sweep(values, ops: OpBatch, funs, results, *, mask_flat,
                       pad_uid):
-    """Apply ops one at a time in global (ts, slot) order (S2PL schedule)."""
-    n = ops.n_ops
+    """Apply the valid masked ops one at a time in global (ts, slot) order
+    (S2PL schedule).  Returns ``(values, results, ops applied)``."""
     order = jnp.lexsort((ops.slot, ops.ts))  # global timestamp order
 
-    def step(carry, i):
+    def step(j, carry):
         values, res = carry
-        j = order[i]
-        run = mask_flat[j] & ops.valid[j]
-        uid = jnp.where(run, ops.uid[j], pad_uid)
+        uid = ops.uid[j]
         cur = values[uid]
         gate = ops.gate[j]
         gate_ok = jnp.where(gate >= 0, res["success"][jnp.maximum(gate, 0)],
@@ -479,19 +497,19 @@ def _sequential_sweep(values, ops: OpBatch, funs, results, *, mask_flat,
         post, ok = funs_apply_single(funs, ops.fun[j], cur, ops.operand[j])
         post = jnp.where(gate_ok, post, cur)
         ok = ok & gate_ok
-        values = values.at[uid].set(jnp.where(run, post, values[pad_uid]))
+        values = values.at[uid].set(post)
         values = values.at[pad_uid].set(0.0)
-        sink = jnp.where(run, j, n)
         res = dict(
-            pre=res["pre"].at[sink].set(cur),
-            post=res["post"].at[sink].set(post),
-            success=res["success"].at[sink].set(ok),
+            pre=res["pre"].at[j].set(cur),
+            post=res["post"].at[j].set(post),
+            success=res["success"].at[j].set(ok),
         )
-        return (values, res), None
+        return values, res
 
-    (values, results), _ = jax.lax.scan(step, (values, results),
-                                        jnp.arange(n))
-    return values, results
+    (values, results), count = sweep_selected(
+        step, (values, results), order, mask_flat & ops.valid)
+    # the pad row reads 0 after the sweep even when no op ran
+    return values.at[pad_uid].set(0.0), results, count
 
 
 def funs_apply_single(funs, fid, pre, operand):
@@ -502,12 +520,13 @@ def eval_lock(store: StateStore, ops: OpBatch, funs):
     """LOCK baseline == sequential oracle (conflict-equivalent ts order)."""
     n = ops.n_ops
     results = _empty_results(n, ops.width)
-    values, results = _sequential_sweep(
+    values, results, _ = _sequential_sweep(
         store.values, ops, funs, results,
         mask_flat=jnp.ones((n,), bool), pad_uid=store.pad_uid)
     results = {k: v[:n] for k, v in results.items()}
     stats = EngineStats(rounds=jnp.sum(ops.valid), n_chains=jnp.int32(1),
-                        max_chain=jnp.sum(ops.valid), n_ops=n,
+                        max_chain=jnp.sum(ops.valid),
+                        fallback_ops=jnp.int32(0), n_ops=n,
                         scheme="lock", path="sequential")
     return results, values, stats
 
@@ -536,7 +555,8 @@ def eval_mvlk(store: StateStore, ops: OpBatch, funs,
         store, ops, funs, has_gates=has_gates, max_dep_levels=max_dep_levels,
         prestructured=prestructured)
     stats = EngineStats(rounds=write_depth, n_chains=ch.n_chains,
-                        max_chain=st.max_chain, n_ops=ops.n_ops,
+                        max_chain=st.max_chain,
+                        fallback_ops=st.fallback_ops, n_ops=ops.n_ops,
                         scheme="mvlk", path="mv")
     return res, values, stats
 
@@ -638,7 +658,8 @@ def eval_pat(store: StateStore, ops: OpBatch, funs, *, n_partitions: int = 16):
         cond, body, (values, results, front, fired, jnp.int32(0)))
     results = {k: v[:n] for k, v in results.items()}
     stats = EngineStats(rounds=rounds, n_chains=jnp.int32(n_partitions),
-                        max_chain=jnp.max(part_len[:n_partitions]), n_ops=n,
+                        max_chain=jnp.max(part_len[:n_partitions]),
+                        fallback_ops=jnp.int32(0), n_ops=n,
                         scheme="pat", path="partition")
     return results, values, stats
 
@@ -659,8 +680,8 @@ def eval_nolock(store: StateStore, ops: OpBatch, funs):
                success=jnp.concatenate([ok, ok[:1]]))
     res = {k: v[: ops.n_ops] for k, v in res.items()}
     stats = EngineStats(rounds=jnp.int32(1), n_chains=jnp.int32(ops.n_ops),
-                        max_chain=jnp.int32(1), n_ops=ops.n_ops,
-                        scheme="nolock", path="parallel")
+                        max_chain=jnp.int32(1), fallback_ops=jnp.int32(0),
+                        n_ops=ops.n_ops, scheme="nolock", path="parallel")
     return res, values, stats
 
 

@@ -434,12 +434,16 @@ def _eval_interval(store: StateStore, ops, *, app: AppSpec,
                 sops, ch = pres
                 pres2 = (dataclasses.replace(
                     sops, valid=sops.valid & ch.take(keep)), ch)
+            first = stats
             res, values, stats = evaluate(
                 store, ops2, app.funs, cfg.scheme,
                 associative_only=app.associative_only,
                 has_gates=app.has_gates, n_partitions=cfg.n_partitions,
                 max_dep_levels=cfg.max_dep_levels,
                 use_pallas=cfg.use_pallas, prestructured=pres2)
+            # the interval's fallback work is both passes'
+            stats = dataclasses.replace(
+                stats, fallback_ops=stats.fallback_ops + first.fallback_ops)
 
     return res, values, stats
 

@@ -60,8 +60,8 @@ from jax.sharding import PartitionSpec as P
 
 from .blotter import AppSpec, build_opbatch
 from .engines import (apply_funs, funs_apply_single, simple_affine_luts,
-                      tstream_scan_coefs_stream, tstream_scan_execute,
-                      tstream_scan_plan)
+                      sweep_selected, tstream_scan_coefs_stream,
+                      tstream_scan_execute, tstream_scan_plan)
 from .ownership import (LAYOUTS, bucket_by_owner, build_ownership,
                         build_probe_route, chunk_shard_output,
                         exchange_capacity, make_local_store, migration_plan,
@@ -946,17 +946,14 @@ def _residue_sharded(vals, sops, ch, unresolved, res, succ_glob, *,
              fun=gather(sops.fun), gate=gather(sops.gate),
              operand=gather(sops.operand), run=gather(un_ops),
              gflat=gather(jnp.where(un_ops, gflat_s, N_glob)))
-    ng = g["uid"].shape[0]
     order = jnp.lexsort((g["slot"], g["ts"]))
     gres = dict(pre=jnp.zeros((N_glob + 1, Wq)),
                 post=jnp.zeros((N_glob + 1, Wq)),
                 success=succ_glob)
 
-    def step(carry, i):
+    def step(j, carry):
         values, gres = carry
-        j = order[i]
-        run = g["run"][j]
-        uid = jnp.where(run, g["uid"][j], s_pad)
+        uid = g["uid"][j]
         cur = values[uid]
         gate = g["gate"][j]
         gate_ok = jnp.where(gate >= 0,
@@ -964,18 +961,18 @@ def _residue_sharded(vals, sops, ch, unresolved, res, succ_glob, *,
         post, ok = funs_apply_single(funs, g["fun"][j], cur, g["operand"][j])
         post = jnp.where(gate_ok, post, cur)
         ok = ok & gate_ok
-        values = values.at[uid].set(jnp.where(run, post, values[s_pad]))
+        values = values.at[uid].set(post)
         values = values.at[s_pad].set(0.0)
-        sink = jnp.where(run, g["gflat"][j], N_glob)
+        sink = g["gflat"][j]
         gres = dict(
             pre=gres["pre"].at[sink].set(cur),
             post=gres["post"].at[sink].set(post),
             success=gres["success"].at[sink].set(ok),
         )
-        return (values, gres), None
+        return values, gres
 
-    (vals_full, gres), _ = jax.lax.scan(step, (vals_full, gres),
-                                        jnp.arange(ng))
+    (vals_full, gres), _ = sweep_selected(step, (vals_full, gres), order,
+                                          g["run"])
 
     vals_new = jnp.concatenate(
         [jax.lax.dynamic_slice_in_dim(vals_full, dev * per, per),

@@ -100,7 +100,8 @@ def fused_chain_eval(values: jnp.ndarray, sops, ch, pad_uid: int, *,
         stats = EngineStats(
             rounds=jnp.ceil(jnp.log2(ch.max_len.astype(jnp.float32) + 1)),
             n_chains=ch.n_chains, max_chain=ch.max_len,
-            n_ops=n, scheme="tstream", path="megakernel")
+            fallback_ops=jnp.int32(0), n_ops=n, scheme="tstream",
+            path="megakernel")
         return res, new_values, stats
 
     res, new_values, stats = fused_chain_eval_ref(values, sops, ch, pad_uid,

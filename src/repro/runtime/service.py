@@ -539,7 +539,7 @@ class StreamService:
                         else eng.cfg.scheme),
                 rung=route[0], path=route[1],
                 fail=0, ops=0, max_chain=0, n_chains=0, rounds=0,
-                x_drop=0, x_ship=0, x_fill=0, x_cap=0)
+                fallback_ops=0, x_drop=0, x_ship=0, x_fill=0, x_cap=0)
             tele.count("chunks", rung=route[0], path=route[1])
             suc = np.asarray(suc)
             entry["ops"] = int(suc.size)
@@ -548,6 +548,9 @@ class StreamService:
                 entry["max_chain"] = int(np.max(est.max_chain))
                 entry["n_chains"] = int(np.min(est.n_chains))
                 entry["rounds"] = int(np.max(est.rounds))
+                # ops the lockstep path's cycle fallback applied
+                entry["fallback_ops"] = int(np.sum(est.fallback_ops))
+                tele.count("engine.fallback_ops", entry["fallback_ops"])
             if xs is not None:
                 st = xs
                 dropped_now = int(np.sum(st["dropped"]))

@@ -245,8 +245,8 @@ def test_chunk_record_ring_schema_and_bound():
     chunks = rec.stats["chunks"]
     assert len(chunks) == 3, "ring bound not enforced"
     keys = {"i", "g0", "k", "events", "lat_s", "qfill", "scheme", "fail",
-            "ops", "max_chain", "n_chains", "rounds", "x_drop", "x_ship",
-            "x_fill", "x_cap"}
+            "ops", "max_chain", "n_chains", "rounds", "fallback_ops",
+            "x_drop", "x_ship", "x_fill", "x_cap"}
     assert all(keys <= set(r) for r in chunks)
     assert [r["i"] for r in chunks] == [5, 6, 7], "newest-last ordering"
     assert all(r["max_chain"] >= 1 and r["ops"] >= 16 for r in chunks), \
