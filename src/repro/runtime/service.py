@@ -118,7 +118,11 @@ Unified telemetry plane (DESIGN.md §2.11, ``runtime/telemetry.py``):
   ``jax.profiler`` trace over the run.
 * Every run counts its compilations (``jax.compiles``) and its chunks by
   restructure rung and evaluation path (``chunks``); each chunk record
-  carries its ``rung`` and ``path``.
+  carries its ``rung`` and ``path``.  On the sharded engine each commit
+  also counts the exchange's rows (``exchange.rows{kind=shipped}``: rows
+  that carried an op; ``{kind=padding}``: bucket rows shipped empty) and
+  raises the gauge ``exchange.fill_max`` (largest bucket fill over its
+  capacity).
 * Replay-safety contract: telemetry never feeds ``decide()`` — a
   tracing-enabled run is bitwise identical to a tracing-off run,
   including crash → restore → replay (tests/test_telemetry.py).  The
@@ -380,6 +384,11 @@ class StreamService:
         # traced runs: each chunk program's (jit, argument specs) by shape,
         # for its op -> phase table once the run is over
         programs: Dict = {}
+        # exchange rows one interval ships: a bucket of ``capacity`` rows
+        # from every device to every route target
+        x_buckets = (eng._sharded.n_dev * eng._sharded.n_route
+                     if eng._sharded is not None else 0)
+        x_stat = dict(fill_max=0.0)     # largest bucket fill / capacity
         init = eng.init_store.values if values is None else values
         src = iter(source)
         state = dict(exhausted=False, to_skip=int(skip_intervals), err=None)
@@ -562,6 +571,15 @@ class StreamService:
                 entry["x_fill"] = (int(np.max(st["max_fill"]))
                                    if np.size(st["max_fill"]) else 0)
                 entry["x_cap"] = int(st["capacity"])
+                # rows that carried an op, and the padding that filled
+                # each bucket up to its capacity
+                x_pad = x_buckets * entry["x_cap"] * kk - entry["x_ship"]
+                tele.count("exchange.rows", entry["x_ship"], kind="shipped")
+                tele.count("exchange.rows", x_pad, kind="padding")
+                if entry["x_cap"]:
+                    x_stat["fill_max"] = max(x_stat["fill_max"],
+                                             entry["x_fill"] / entry["x_cap"])
+                    tele.gauge("exchange.fill_max", x_stat["fill_max"])
                 sl = st.get("shard_load")
                 if sl is not None:
                     # per-shard access histogram (state rows touched on

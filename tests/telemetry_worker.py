@@ -7,7 +7,10 @@ verdicts for tests/test_telemetry.py:
   tracing-off run (final state + every per-interval output);
 * crash -> restore -> replay with tracing on reproduces the untraced
   uninterrupted run bitwise, while the trace validates against the
-  pipeline-stage schema (including ``reshard``-free sharded spans).
+  pipeline-stage schema (including ``reshard``-free sharded spans);
+* on a 4-device mesh, the exchange counters (``exchange.rows``,
+  ``exchange.fill_max``) agree with the chunk records, and the chunk
+  program's ``op_phases`` table names its exchange.
 """
 import os
 
@@ -29,6 +32,7 @@ from repro.core.scheduler import DualModeEngine, EngineConfig   # noqa: E402
 from repro.core.sharded_stream import stream_mesh               # noqa: E402
 from repro.runtime.service import ServiceConfig, StreamService  # noqa: E402
 from repro.runtime.telemetry import (PIPELINE_STAGES, TelemetryConfig,
+                                     counter_value, gauge_value,
                                      validate_trace)            # noqa: E402
 
 MESH = stream_mesh((8,), ("dev",))
@@ -130,6 +134,43 @@ def check_traced_crash_resume(app_name):
         return dict(ok=True, resumed_from=snap)
 
 
+def check_exchange_counters(app_name, n_dev=4):
+    app = ALL_APPS[app_name]
+    mesh = stream_mesh((n_dev,), ("dev",), devices=jax.devices()[:n_dev])
+    eng = DualModeEngine(app, app.make_store(), EngineConfig(), mesh=mesh,
+                         exchange_slack=2.0)
+    with tempfile.TemporaryDirectory() as tmp:
+        trace = os.path.join(tmp, "trace.json")
+        rec = StreamService(eng, ServiceConfig(
+            punct_interval=INTERVAL, chunk_intervals=2,
+            watermark=WatermarkPolicy(allowed_lateness=4),
+            telemetry=TelemetryConfig(trace_path=trace))).run(
+                _mk_source(app))
+        with open(trace) as f:
+            events = json.load(f)
+    snap = rec.telemetry.snapshot()
+    chunks = rec.chunk_records
+    ship = sum(c["x_ship"] for c in chunks)
+    pad = sum(n_dev * n_dev * c["x_cap"] * c["k"] - c["x_ship"]
+              for c in chunks)
+    fill = max(c["x_fill"] / c["x_cap"] for c in chunks)
+    got = dict(
+        shipped=counter_value(snap, "exchange.rows", kind="shipped"),
+        padding=counter_value(snap, "exchange.rows", kind="padding"),
+        fill_max=gauge_value(snap, "exchange.fill_max"))
+    want = dict(shipped=ship, padding=pad, fill_max=fill)
+    if got != want or ship != rec.exchange_shipped or not ship or not pad:
+        return dict(ok=False, why=f"counters {got} != chunk records {want}")
+    tables = [e["args"] for e in events
+              if e.get("ph") == "M" and e["name"] == "op_phases"]
+    if [t["name"] for t in tables] != ["jit_tstream_chunk_sharded"]:
+        return dict(ok=False, why=f"op_phases tables {tables!r}")
+    phases = set(tables[0]["phases"].values())
+    if not {"exchange", "restructure", "evaluate"} <= phases:
+        return dict(ok=False, why=f"op_phases names {sorted(phases)}")
+    return dict(ok=True, chunks=len(chunks), shipped=ship, padding=pad)
+
+
 def main():
     out = {}
 
@@ -142,6 +183,7 @@ def main():
 
     run("gs/traced_identical", check_traced_sharded_identical, "gs")
     run("gs/traced_crash_resume", check_traced_crash_resume, "gs")
+    run("gs/exchange_counters_4dev", check_exchange_counters, "gs")
     print(json.dumps(out))
 
 

@@ -371,7 +371,8 @@ def telemetry_worker_verdicts():
 
 
 @pytest.mark.parametrize("case", ["gs/traced_identical",
-                                  "gs/traced_crash_resume"])
+                                  "gs/traced_crash_resume",
+                                  "gs/exchange_counters_4dev"])
 def test_sharded_telemetry_replay_safety(telemetry_worker_verdicts, case):
     v = telemetry_worker_verdicts[case]
     assert v["ok"], f"{case}: {v.get('why')}"

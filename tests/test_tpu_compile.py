@@ -13,7 +13,8 @@ Widths: a GS interval is 500 events x 10 accesses = 5,000 rows; an SL
 interval 500 x 4 = 2,000 rows, batched over a chunk of 4 intervals; the
 sharded route probes a 10,000-uid table (2,500 buckets); the megakernel
 is compiled at its fit bounds (``MEGA_MAX_ROWS`` rows x
-``MEGA_MAX_CELLS / MEGA_MAX_ROWS`` slots).
+``MEGA_MAX_CELLS / MEGA_MAX_ROWS`` slots).  The sharded chunk program of
+the 4-chip cell ``gs_mp4.backlog`` is compiled whole for the 2x2 mesh.
 """
 from functools import partial
 
@@ -37,10 +38,10 @@ LANES = 128
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """One chip of a described v5e:2x2, with the persistent compilation
-    cache off: a compile for a described chip is written to the cache but
-    cannot be read back without one."""
+def v5e_2x2():
+    """A described v5e:2x2, with the persistent compilation cache off: a
+    compile for a described chip is written to the cache but cannot be
+    read back without one."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     with pytest.MonkeyPatch.context() as mp:
@@ -54,10 +55,16 @@ def v5e():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    """One chip of the described v5e:2x2."""
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def compiled_kernels(fn, *shapes):
@@ -120,3 +127,38 @@ def test_megakernel_compiles_at_fit_bounds(v5e):
                              spec(v5e, (rows, 1), jnp.int32),
                              f32((rows, LANES)), f32((slots, LANES)))
     assert found["fused_chain"] == 1, found
+
+
+def test_sharded_chunk_program_compiles_on_the_2x2_mesh(v5e_2x2,
+                                                       monkeypatch):
+    """``gs_mp4``'s chunk program (GS, 10,000 records, four 500-event
+    intervals per chunk, ``exchange_slack`` 8) for the 4-chip mesh, routed
+    as on the chip; its all-to-alls are in the op table's exchange phase."""
+    import re
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.apps import ALL_APPS
+    from repro.core.scheduler import EngineConfig
+    from repro.core.sharded_stream import ShardedStream, stream_mesh
+    from repro.runtime.telemetry import op_phase_table
+    kind = v5e_2x2.devices[0].device_kind
+    canon = autotune._canon_kind
+    monkeypatch.setattr(autotune, "_canon_kind",
+                        lambda k=None: canon(kind if k is None else k))
+    mesh = stream_mesh((4,), ("dev",), devices=v5e_2x2.devices)
+    app = ALL_APPS["gs"]
+    eng = ShardedStream(app, app.make_store(), EngineConfig(), mesh,
+                        exchange_slack=8.0)
+    assert eng.chunk_route(500) == ("mega", "segscan")
+    rep = partial(spec, NamedSharding(mesh, P()))
+    batched = dict(keys=rep((4, 500, 10), jnp.int32),
+                   is_read=rep((4, 500), jnp.bool_),
+                   values=rep((4, 500, 10), jnp.float32))
+    blocks = spec(NamedSharding(mesh, P("dev")),
+                  (4 * (eng.own.per + 1), 1), jnp.float32)
+    text = eng._impl.lower(blocks, batched, jax.ShapeDtypeStruct(
+        (), jnp.int32)).compile().as_text()
+    table = op_phase_table(text)
+    assert table["program"] == "jit_tstream_chunk_sharded"
+    a2a = re.findall(r"^\s+%?([\w.\-]+) = \S+ all-to-all\(", text, re.M)
+    assert len(a2a) >= 2, "the exchange out and its reverse"
+    assert {table["phases"][op] for op in a2a} == {"exchange"}
