@@ -56,17 +56,19 @@ Prestructured = Tuple[OpBatch, Chains]
 # Fun application
 # ---------------------------------------------------------------------------
 def apply_funs(funs: Tuple[FunSpec, ...], fun_id: jnp.ndarray,
-               pre: jnp.ndarray, operand: jnp.ndarray):
+               pre: jnp.ndarray, operand: jnp.ndarray, *, op_axis: int = 0):
     """Vectorized lax.switch over the app's fun family.
 
-    pre, operand: [N, W] -> (post [N, W], success bool[N]).
+    pre, operand: [N, W] -> (post [N, W], success bool[N]); with
+    ``op_axis=1`` they are op-minor [W, N] and so is post.
     """
     branches = [f.apply for f in funs]
 
     def one(fid, p, o):
         return jax.lax.switch(fid, branches, p, o)
 
-    return jax.vmap(one)(fun_id, pre, operand)
+    return jax.vmap(one, in_axes=(0, op_axis, op_axis),
+                    out_axes=(op_axis, 0))(fun_id, pre, operand)
 
 
 def affine_coeffs(funs: Tuple[FunSpec, ...], fun_id: jnp.ndarray,
@@ -110,11 +112,6 @@ def simple_affine_luts(funs: Tuple[FunSpec, ...]):
         return None
     return (jnp.asarray([s[0] for s in simple], jnp.float32),
             jnp.asarray([s[1] for s in simple]))
-
-
-def _gate_open(gate: jnp.ndarray, success_flat: jnp.ndarray) -> jnp.ndarray:
-    """CFun gating: open when ungated, else the mate op's recorded success."""
-    return jnp.where(gate >= 0, jnp.take(success_flat, jnp.maximum(gate, 0)), True)
 
 
 @dataclasses.dataclass
@@ -388,34 +385,69 @@ def _chain_levels(sops: OpBatch, ch: Chains, n: int, max_levels: int):
 
 
 def _lockstep_sweep(values, sops: OpBatch, ch: Chains,
-                    funs: Tuple[FunSpec, ...], chain_mask, results, n, pad_uid,
-                    rounds):
-    """Walk masked chains in lockstep: round r applies each chain's r-th op.
+                    funs: Tuple[FunSpec, ...], lvl_s, n_levels: int, pad_uid):
+    """Walk the chains of levels ``0 .. n_levels-1`` (``lvl_s``: each sorted
+    op's chain level) level by level; round r of a level applies the r-th
+    op of each of its chains.
 
-    Exactly one op per state per round -> conflict-free scatters, no locks.
+    A round is element-wise over per-op arrays in sorted (chain) layout,
+    op axis minor.  A chain is one state and its ops are contiguous, so
+    op i+1 reads the value op i left (a shift by one row); a gated op's
+    mate lies in a lower level, so its success is read once per level.
+    The table is gathered once and written once, with each walked chain's
+    value after its last valid op.  Returns ``(values, results in flat
+    layout [N rows], round count per level)``.
     """
-    def round_body(r, carry):
-        values, res = carry
-        active = (ch.pos == r) & jnp.take(chain_mask, ch.seg_id) & sops.valid
-        cur = jnp.take(values, sops.uid, axis=0)
-        # sops.gate holds the mate's *pre-sort* flat index; success is
-        # recorded in pre-sort layout, so this gather is layout-consistent.
-        gate_ok_s = _gate_open(sops.gate, res["success"][:-1])
-        post, ok = apply_funs(funs, sops.fun, cur, sops.operand)
-        post = jnp.where(gate_ok_s[:, None], post, cur)
-        ok = ok & gate_ok_s
-        scat = jnp.where(active, sops.uid, pad_uid)
-        values = values.at[scat].set(jnp.where(active[:, None], post, 0.0))
-        values = values.at[pad_uid].set(0.0)
-        sink = jnp.where(active, ch.order, n)
-        res = dict(
-            pre=res["pre"].at[sink].set(cur),
-            post=res["post"].at[sink].set(post),
-            success=res["success"].at[sink].set(ok),
-        )
-        return values, res
+    n, w = sops.operand.shape
+    valid = sops.valid
+    operand = sops.operand.T                               # [W, N]
+    cur = jnp.take(values, sops.uid, axis=0).T             # [W, N]
+    gate_s = jnp.take(ch.inv, jnp.maximum(sops.gate, 0))   # mate, sorted
 
-    return jax.lax.fori_loop(0, rounds, round_body, (values, results))
+    carry = (cur, jnp.zeros((w, n), values.dtype),
+             jnp.zeros((w, n), values.dtype), jnp.zeros((n,), bool))
+    lvl_rounds = []
+    for level in range(n_levels):
+        in_level = lvl_s == level
+        rounds = jnp.max(jnp.where(in_level & valid, ch.pos, -1)) + 1
+        gate_ok = jnp.where(sops.gate >= 0, jnp.take(carry[3], gate_s),
+                            True)
+
+        def round_body(r, carry, in_level=in_level, gate_ok=gate_ok):
+            cur, pre, post, success = carry
+            step = (ch.pos == r) & in_level
+            active = step & valid
+            out, ok = apply_funs(funs, sops.fun, cur, operand, op_axis=1)
+            out = jnp.where(gate_ok, out, cur)
+            ok = ok & gate_ok
+            pre = jnp.where(active, cur, pre)
+            post = jnp.where(active, out, post)
+            success = jnp.where(active, ok, success)
+            # the next op of each chain (pos r + 1 > 0: row i - 1 is its
+            # chain's op at pos r) reads what that op left; an invalid op
+            # hands on the value it found
+            left = jnp.where(active, out, cur)
+            left = jnp.concatenate([left[:, :1], left[:, :-1]], axis=1)
+            cur = jnp.where((ch.pos == r + 1) & in_level, left, cur)
+            return cur, pre, post, success
+
+        carry = jax.lax.fori_loop(0, rounds, round_body, carry)
+        lvl_rounds.append(rounds)
+    _, pre, post, success = carry
+
+    # each walked chain's last valid op writes the table: the next valid
+    # row after it lies beyond its chain's end
+    idx = jnp.arange(n, dtype=jnp.int32)
+    next_valid = jax.lax.cummin(jnp.where(valid, idx, n), reverse=True)
+    chain_end = jax.lax.cummin(jnp.where(ch.seg_end, idx, n), reverse=True)
+    later = jnp.concatenate([next_valid[1:], jnp.full((1,), n, jnp.int32)])
+    last = valid & (lvl_s < n_levels) & (later > chain_end)
+    scat = jnp.where(last, sops.uid, pad_uid)
+    values = values.at[scat].set(jnp.where(last[:, None], post.T, 0.0))
+    values = values.at[pad_uid].set(0.0)
+    results = dict(pre=ch.untake(pre.T), post=ch.untake(post.T),
+                   success=ch.untake(success))
+    return values, results, lvl_rounds
 
 
 def eval_tstream_lockstep(store: StateStore, ops: OpBatch,
@@ -425,27 +457,20 @@ def eval_tstream_lockstep(store: StateStore, ops: OpBatch,
     sops, ch = (restructure(ops, store.pad_uid) if prestructured is None
                 else prestructured)
     n = ops.n_ops
-    values = store.values
-    results = _empty_results(n, ops.width)
 
     if not has_gates:
-        values, results = _lockstep_sweep(
-            values, sops, ch, funs, jnp.ones((n,), bool), results, n,
-            store.pad_uid, ch.max_len)
+        # one level: every chain
+        values, results, _ = _lockstep_sweep(
+            store.values, sops, ch, funs, jnp.zeros((n,), jnp.int32), 1,
+            store.pad_uid)
         rounds = ch.max_len
         fallback_ops = jnp.int32(0)
     else:
         lvl, unresolved = _chain_levels(sops, ch, n, max_dep_levels)
-        rounds = jnp.int32(0)
-        for L in range(max_dep_levels + 1):
-            mask = (lvl == L)
-            # this level's sweep only needs the longest level-L chain
-            in_level = jnp.take(mask, ch.seg_id) & sops.valid
-            lvl_rounds = jnp.max(jnp.where(in_level, ch.pos, -1)) + 1
-            values, results = _lockstep_sweep(
-                values, sops, ch, funs, mask, results, n, store.pad_uid,
-                lvl_rounds)
-            rounds = rounds + lvl_rounds
+        values, results, lvl_rounds = _lockstep_sweep(
+            store.values, sops, ch, funs, jnp.take(lvl, ch.seg_id),
+            max_dep_levels + 1, store.pad_uid)
+        rounds = sum(lvl_rounds, jnp.int32(0))
         # sequential fallback for ops in unresolved chains (cycles)
         unresolved_ops_sorted = jnp.take(unresolved, ch.seg_id) & sops.valid
         unresolved_ops = ch.untake(unresolved_ops_sorted)
@@ -454,11 +479,10 @@ def eval_tstream_lockstep(store: StateStore, ops: OpBatch,
             pad_uid=store.pad_uid)
         rounds = rounds + fallback_ops
 
-    res = {k: v[:n] for k, v in results.items()}
     stats = EngineStats(rounds=rounds, n_chains=ch.n_chains,
                         max_chain=ch.max_len, fallback_ops=fallback_ops,
                         n_ops=n, scheme="tstream", path="lockstep")
-    return res, values, stats
+    return results, values, stats
 
 
 # ---------------------------------------------------------------------------

@@ -868,9 +868,11 @@ def _lockstep_eval(vals, sops, ch, *, eng: ShardedStream, R, N_glob,
 
 def _sweep_sharded(values, sops, ch, funs, chain_mask, res, n, pad_uid,
                    rounds, succ_glob, g2l):
-    """`engines._lockstep_sweep` with gate successes resolved locally when
-    the mate op lives on this device (same-chain gates) and through the
-    merged global frontier otherwise."""
+    """A lockstep sweep that scatters into the table and the results each
+    round (``engines._lockstep_sweep`` walks in sorted layout instead),
+    with gate successes resolved locally when the mate op lives on this
+    device (same-chain gates) and through the merged global frontier
+    otherwise."""
     def round_body(r, carry):
         values, res = carry
         active = (ch.pos == r) & jnp.take(chain_mask, ch.seg_id) & sops.valid

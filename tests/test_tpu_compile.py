@@ -162,3 +162,37 @@ def test_sharded_chunk_program_compiles_on_the_2x2_mesh(v5e_2x2,
     a2a = re.findall(r"^\s+%?([\w.\-]+) = \S+ all-to-all\(", text, re.M)
     assert len(a2a) >= 2, "the exchange out and its reverse"
     assert {table["phases"][op] for op in a2a} == {"exchange"}
+
+
+def test_sl_chunk_program_walks_chains_lane_dense(v5e, monkeypatch):
+    """``sl_paper``'s chunk program (SL, abort repass, four 500-event
+    intervals per chunk) for one v5e: each lockstep level loop (four
+    dependency levels, first pass and repass) carries its per-op arrays
+    with the op axis minor, ``f32[1,2000]``, no ``[2000,1]`` column laid
+    out op-major (one op per 128-lane row), and not the table."""
+    import re
+
+    import numpy as np
+    from repro.apps import ALL_APPS
+    from repro.core.scheduler import DualModeEngine, EngineConfig
+    kind = next(iter(v5e.device_set)).device_kind
+    canon = autotune._canon_kind
+    monkeypatch.setattr(autotune, "_canon_kind",
+                        lambda k=None: canon(kind if k is None else k))
+    app = ALL_APPS["sl"]
+    store = app.make_store()
+    cfg = EngineConfig(abort_repass=True)
+    eng = DualModeEngine(app, store, cfg)
+    sample = app.gen_events(np.random.default_rng(0), 500)
+    batched = {k: spec(v5e, (4,) + np.shape(v), np.asarray(v).dtype)
+               for k, v in sample.items()}
+    text = eng.chunk_program().lower(
+        spec(v5e, store.values.shape, store.values.dtype), batched,
+        spec(v5e, (), jnp.int32)).compile().as_text()
+    carries = [m.group(1) for m in re.finditer(
+        r"^\s+%?while[\w.\-]* = \((.*?)\) while\(", text, re.M)]
+    rounds = [c for c in carries if f"f32[1,{SL_ROWS}]" in c]
+    assert len(rounds) == 2 * (cfg.max_dep_levels + 1), carries
+    for c in rounds:
+        assert f"[{SL_ROWS},1]{{1,0" not in c, c
+        assert f"[{store.values.shape[0]},1]" not in c, c
